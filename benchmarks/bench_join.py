@@ -18,7 +18,7 @@ import jax
 import numpy as np
 
 sys.path.insert(0, ".")
-from benchmarks.common import ROCE_LINE_RATE_GBPS, emit, maybe_spoof_cpu, time_iters
+from benchmarks.common import ROCE_LINE_RATE_GBPS, emit, time_iters
 
 from sparkrdma_tpu.models.join import (
     make_broadcast_join_step,
@@ -29,7 +29,6 @@ from sparkrdma_tpu.parallel.mesh import make_mesh
 
 
 def main():
-    maybe_spoof_cpu()
     log2 = int(sys.argv[1]) if len(sys.argv) > 1 else 22
     n_fact = 1 << log2
     n_dim = 1 << max(10, log2 - 6)

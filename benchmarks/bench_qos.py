@@ -29,9 +29,7 @@ import time
 import numpy as np
 
 sys.path.insert(0, ".")
-from benchmarks.common import RESULTS, emit, maybe_spoof_cpu  # noqa: E402
-
-maybe_spoof_cpu()
+from benchmarks.common import RESULTS, emit  # noqa: E402
 
 SMOKE = bool(os.environ.get("BENCH_SMOKE"))
 SMOKE_DIR = "/tmp" if SMOKE else None

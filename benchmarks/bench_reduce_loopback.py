@@ -24,7 +24,7 @@ import time
 import numpy as np
 
 sys.path.insert(0, ".")
-from benchmarks.common import RESULTS, emit, maybe_spoof_cpu
+from benchmarks.common import RESULTS, emit
 
 from sparkrdma_tpu.api import TpuShuffleContext
 
@@ -757,7 +757,6 @@ def main():
 
         TRACING.retain(1.0)
         RECORDER.retain(ring_size=4096)
-    maybe_spoof_cpu()
     rng = np.random.default_rng(1)
     records = [(int(k), 1) for k in rng.integers(0, N_KEYS, N_RECORDS)]
 

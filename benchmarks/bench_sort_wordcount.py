@@ -22,7 +22,6 @@ sys.path.insert(0, ".")
 from benchmarks.common import (
     ROCE_LINE_RATE_GBPS,
     emit,
-    maybe_spoof_cpu,
     time_iters,
     zipf_keys,
 )
@@ -32,7 +31,6 @@ from sparkrdma_tpu.parallel.mesh import make_mesh
 
 
 def main():
-    maybe_spoof_cpu()
     log2 = int(sys.argv[1]) if len(sys.argv) > 1 else 23
     n = 1 << log2
     mesh = make_mesh()

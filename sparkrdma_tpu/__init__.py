@@ -21,66 +21,6 @@ Layer map (mirrors SURVEY.md §1):
     L5  device    arenas, pallas kernels   (memory/, ops/)
 """
 
-# jax compatibility: every collective program here builds on
-# ``jax.shard_map``, which older jax releases (< 0.4.38, e.g. the
-# 0.4.37 this image ships) only expose as
-# ``jax.experimental.shard_map.shard_map``.  Bridge it once at package
-# import so all call sites (and the test fixtures that mirror them)
-# keep the one modern spelling.
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):  # pragma: no cover - version-dependent
-    try:
-        import functools as _functools
-
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        @_functools.wraps(_shard_map)
-        def _shard_map_compat(f, *args, **kw):
-            # the modern kwarg spelling on the experimental signature
-            if "check_vma" in kw:
-                kw["check_rep"] = kw.pop("check_vma")
-            return _shard_map(f, *args, **kw)
-
-        _jax.shard_map = _shard_map_compat
-    except ImportError:
-        pass  # truly ancient jax: call sites fail loudly as before
-
-if not hasattr(_jax.lax, "axis_size"):  # pragma: no cover
-    def _axis_size(axis_name):
-        """jax<0.4.38 spelling: the static mesh-axis size lives on the
-        core axis frame (older frames ARE the size)."""
-        frame = _jax.core.axis_frame(axis_name)
-        return getattr(frame, "size", frame)
-
-    _jax.lax.axis_size = _axis_size
-
-if not hasattr(_jax.lax, "pcast"):  # pragma: no cover
-    def _pcast(x, axis_name=None, *, to=None):
-        """jax<0.5 has no varying/unvarying mesh-axis typing (vma), so
-        the cast that converts between them is the identity there."""
-        del axis_name, to
-        return x
-
-    _jax.lax.pcast = _pcast
-
-try:  # pragma: no cover - version-dependent
-    _jax.ShapeDtypeStruct((1,), "uint8", vma=frozenset())
-except TypeError:
-    _OrigSDS = _jax.ShapeDtypeStruct
-
-    class _ShapeDtypeStructCompat(_OrigSDS):
-        """Pre-vma jax: accept and drop the varying-mesh-axes kwarg
-        (no vma typing exists to propagate it to)."""
-
-        def __init__(self, shape, dtype, **kw):
-            kw.pop("vma", None)
-            super().__init__(shape, dtype, **kw)
-
-    _jax.ShapeDtypeStruct = _ShapeDtypeStructCompat
-except BaseException:
-    pass
-
 from sparkrdma_tpu.conf import TpuShuffleConf
 from sparkrdma_tpu.utils.columns import ColumnBatch
 from sparkrdma_tpu.utils.types import (

@@ -182,6 +182,9 @@ class TpuShuffleContext:
             for i in range(num_executors)
         ]
         self._shuffle_ids = itertools.count()
+        # the readPlane=bulk session of the latest shuffle (its
+        # exchange's stats say which data path the bytes took)
+        self.bulk_session = None
         self._stopped = False
 
     # -- dataset creation ---------------------------------------------------
@@ -340,6 +343,7 @@ class TpuShuffleContext:
             out_alloc=self.executors[0].staging_pool.alloc_gc,
             window_rounds=self.conf.device_exchange_window_rounds,
         )
+        self.bulk_session = session
 
         def bulk_task(i: int):
             ex = self.executors[i]

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import os
 import re
+import sys
 from typing import Dict, Mapping, Optional
 
 _SIZE_RE = re.compile(r"^\s*(\d+(?:\.\d+)?)\s*([kmgt]?)b?\s*$", re.IGNORECASE)
@@ -68,11 +69,11 @@ def device_census() -> int:
     ``XLA_FLAGS --xla_force_host_platform_device_count=N`` forcing —
     readable WITHOUT initializing jax, so conf defaults never pin the
     backend choice for the whole process.  Otherwise ask
-    ``jax.device_count()`` (authoritative on real TPU/GPU hosts; the
-    forced-count flag only applies to the cpu platform, so it must not
-    be trusted there).  Answers 1 when jax is unavailable — a 1-device
-    host can then never silently gate (or fake-pass) a
-    multi-device-only default."""
+    ``jax.device_count()`` once this process has started a backend
+    (the forced-count flag only applies to the cpu platform, so it must
+    not be trusted there).  A process that has started none answers 1
+    and starts none: reading a conf default must never make a child
+    process (a spawned executor) reach for a chip its parent holds."""
     platform = os.getenv(
         "JAX_PLATFORMS", os.getenv("JAX_PLATFORM_NAME", "")
     ).strip().lower()
@@ -80,12 +81,14 @@ def device_census() -> int:
         m = _FORCED_DEVICES_RE.search(os.getenv("XLA_FLAGS", ""))
         if m:
             return max(1, int(m.group(1)))
-    try:
-        import jax
-
-        return max(1, jax.device_count())
-    except Exception:
+    if "jax" not in sys.modules:
         return 1
+    # no public query for "has a backend started" exists
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return 1
+    return max(1, sys.modules["jax"].device_count())
 
 
 class TpuShuffleConf:

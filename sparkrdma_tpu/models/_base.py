@@ -127,8 +127,6 @@ class ExchangeModel:
         per device.  Returns ``(rows, nu)``: each of ``rows`` reshaped
         to [D, -1] on the host, ``nu`` the int32[D] valid-row counts.
         """
-        import jax.numpy as jnp
-
         keys = np.asarray(keys)
         vals = np.asarray(vals)
         if keys.shape != vals.shape or keys.ndim != 1:
@@ -149,10 +147,9 @@ class ExchangeModel:
         # step's whole cost on one chip)
         fast = D == 1 and n_pad == 0
         cols = (keys, vals) if fast else (keys, vals, valid)
-        # place once: only the capacity changes between overflow retries
-        placed = tuple(
-            jax.device_put(jnp.asarray(x), self.sharding) for x in cols
-        )
+        # place once, shard by shard (never whole on one device): only
+        # the capacity changes between overflow retries
+        placed = tuple(jax.device_put(x, self.sharding) for x in cols)
 
         def run(cap):
             step = make_step(

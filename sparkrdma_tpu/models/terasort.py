@@ -41,6 +41,14 @@ from sparkrdma_tpu.ops.partition import make_range_splitters
 from sparkrdma_tpu.parallel.mesh import EXCHANGE_AXIS
 
 
+def _sample_positions(n_local: int, sample_size: int) -> np.ndarray:
+    """Exact local quantile positions i*n/S, computed on the host in
+    64 bits: in int32, i*n overflows once S*n reaches 2^31 (n_local
+    2^21 at S=1024), which skews the splitters and overflows buckets."""
+    i = np.arange(sample_size, dtype=np.int64)
+    return (i * n_local // sample_size).astype(np.int32)
+
+
 def _local_sort_step(keys, vals, valid, n_devices, capacity, sample_size):
     """Per-device body (runs under shard_map).  keys/vals: [n_local];
     ``valid`` is int32 0/1 or None (= everything valid, skips the column).
@@ -92,7 +100,7 @@ def _local_sort_step(keys, vals, valid, n_devices, capacity, sample_size):
         k, _, v = jax.lax.sort((keys, inv, vals), num_keys=2, is_stable=False)
         n_real = jnp.sum(valid).astype(jnp.int32)
     # exact local quantiles (k is sorted): positions i*n/S
-    sample = k[(jnp.arange(sample_size) * n_local) // sample_size]
+    sample = k[_sample_positions(n_local, sample_size)]
     all_samples = jax.lax.all_gather(sample, EXCHANGE_AXIS)  # [D, S]
     splitters = make_range_splitters(all_samples.reshape(-1), n_devices)
     # destination windows: device p gets keys in [splitters[p-1], splitters[p])
@@ -183,7 +191,7 @@ def _local_sort_wide_step(keys, payload, n_devices, capacity,
         return k, p, n_valid, jnp.int32(n_local)
     k, perm = jax.lax.sort((keys, iota), num_keys=1, is_stable=False)
     ps = jnp.take(payload, perm, axis=0)
-    sample = k[(jnp.arange(sample_size) * n_local) // sample_size]
+    sample = k[_sample_positions(n_local, sample_size)]
     all_samples = jax.lax.all_gather(sample, EXCHANGE_AXIS)
     splitters = make_range_splitters(all_samples.reshape(-1), n_devices)
     edges = jnp.concatenate([
@@ -377,16 +385,23 @@ class TeraSorter(ExchangeModel):
         return step(keys, payload), cap
 
     def sort(self, keys, vals=None) -> Tuple[np.ndarray, np.ndarray]:
-        """Full host-facing sortByKey: returns (sorted_keys, sorted_vals)."""
+        """Full host-facing sortByKey: returns (sorted_keys, sorted_vals).
+        ``vals`` of shape [n, W] are wide-record payload rows (the
+        HiBench shape) that ride their keys (:meth:`sort_device_wide`)."""
         keys = np.asarray(keys)
         if vals is None:
             vals = np.zeros_like(keys)
         vals = np.asarray(vals)
-        if keys.shape != vals.shape or keys.ndim != 1:
-            raise ValueError("keys/vals must be equal-length 1-D arrays")
+        if keys.ndim != 1 or vals.shape[:1] != keys.shape \
+                or vals.ndim > 2:
+            raise ValueError(
+                "keys must be 1-D and vals [n] or [n, W] rows"
+            )
         n = keys.shape[0]
         if n == 0:
             return keys.copy(), vals.copy()
+        if vals.ndim == 2:
+            return self._sort_wide(keys, vals)
         # pad to a multiple of D on the compile-shape ladder
         # (_base.quantize_padded_length); padding is tracked by the
         # validity column (NOT by key value), so max-valued real keys
@@ -399,10 +414,11 @@ class TeraSorter(ExchangeModel):
             vals = np.concatenate([vals, np.zeros(n_pad, vals.dtype)])
             valid = np.ones(n + n_pad, np.int32)
             valid[n:] = 0
-            jval = jnp.asarray(valid)
+            jval = jax.device_put(valid, self.sharding)
         else:
             jval = None  # fast path: no padding column needed
-        jk, jv = jnp.asarray(keys), jnp.asarray(vals)
+        jk = jax.device_put(keys, self.sharding)
+        jv = jax.device_put(vals, self.sharding)
 
         def run(cap):
             (sk, sv, n_valid, max_fill), _ = self.sort_device(
@@ -419,3 +435,35 @@ class TeraSorter(ExchangeModel):
         out_k = np.concatenate([sk_h[d, : nv[d]] for d in range(D)])
         out_v = np.concatenate([sv_h[d, : nv[d]] for d in range(D)])
         return out_k, out_v
+
+    def _sort_wide(self, keys: np.ndarray, payload: np.ndarray):
+        """Host-facing wide-record sort: rows are placed shard by shard
+        once, sorted on the mesh under the overflow-retry policy, and
+        stitched from the per-device runs.  The wide step carries no
+        validity column, so the length must divide D."""
+        n, D = keys.shape[0], self.n_devices
+        if n % D:
+            raise ValueError(
+                f"wide rows: length {n} not divisible by D={D}"
+            )
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        jk = jax.device_put(keys, self.sharding)
+        jp = jax.device_put(
+            payload, NamedSharding(self.mesh, P(EXCHANGE_AXIS, None))
+        )
+
+        def run(cap):
+            (sk, sp, n_valid, max_fill), _ = self.sort_device_wide(
+                jk, jp, capacity=cap
+            )
+            return (sk, sp, n_valid), max_fill
+
+        sk, sp, n_valid = self._run_with_overflow_retry(n, run)
+        sk_h = np.asarray(sk).reshape(D, -1)
+        sp_h = np.asarray(sp).reshape(D, -1, payload.shape[1])
+        nv = np.asarray(n_valid).reshape(-1)
+        return (
+            np.concatenate([sk_h[d, : nv[d]] for d in range(D)]),
+            np.concatenate([sp_h[d, : nv[d]] for d in range(D)]),
+        )

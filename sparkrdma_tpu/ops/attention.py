@@ -120,10 +120,7 @@ def _pallas_block_attention(q, k, v, q_offset, k_offset, *, causal, scale,
     s_k = k.shape[0]
     # under shard_map the outputs vary over the same mesh axes as the
     # inputs; out_shape must carry that annotation explicitly
-    try:
-        vma = jax.typeof(q).vma
-    except (AttributeError, TypeError):
-        vma = frozenset()
+    vma = jax.typeof(q).vma
     bq = _pick_block(s_q, block_q)
     bk = _pick_block(s_k, block_k)
     grid = (s_q // bq, s_k // bk)

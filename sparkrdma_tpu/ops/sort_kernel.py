@@ -15,10 +15,12 @@ pair order come from 2-D ``broadcasted_iota``.  Ties break by flat
 index, which keeps the two sides of every compare-exchange consistent
 (the pair moves key and value together).
 
-UNVALIDATED ON REAL TPU SILICON: the chip was unreachable when this
-landed, so only interpret-mode semantics are pinned (tests).  Nothing
-dispatches to it by default — call sites must opt in after
-``tools/profile_tpu_sort.py`` shows it beating ``lax.sort`` on chip.
+Blocks default to 512 rows: at 1024 the network's temporaries
+exceed the 16 MiB of scoped VMEM and the TPU compiler refuses the
+kernel (tests/test_chip_compile.py compiles it for v5e).  Interpret
+mode pins its semantics (tests).  Nothing dispatches to it by default
+— call sites must opt in after ``tools/profile_tpu_sort.py`` shows it
+beating ``lax.sort`` on chip.
 """
 
 from __future__ import annotations
@@ -109,7 +111,7 @@ def _block_sort_body(R, interpret, k_ref, v_ref, ok_ref, ov_ref):
 @functools.partial(
     jax.jit, static_argnames=("block_rows", "interpret")
 )
-def sort_pairs_blocks(keys, vals, block_rows: int = 1024,
+def sort_pairs_blocks(keys, vals, block_rows: int = 512,
                       interpret: bool = False):
     """Sort (keys, vals) within consecutive blocks of
     ``block_rows * 128`` elements (each block independently ascending
@@ -142,7 +144,7 @@ def sort_pairs_blocks(keys, vals, block_rows: int = 1024,
     return ok.reshape(-1), ov.reshape(-1)
 
 
-def sort_pairs_full(keys, vals, block_rows: int = 1024,
+def sort_pairs_full(keys, vals, block_rows: int = 512,
                     n_buckets: int = 16, cap_factor: float = 1.4,
                     interpret: bool = False):
     """Full (key, value) sort: Pallas block sorts → equal-frequency
@@ -242,7 +244,7 @@ def sort_pairs_full(keys, vals, block_rows: int = 1024,
     )
 
 
-def sort_pairs_full_checked(keys, vals, block_rows: int = 1024,
+def sort_pairs_full_checked(keys, vals, block_rows: int = 512,
                             n_buckets: int = 16,
                             cap_factor: float = 1.4,
                             interpret: bool = False):

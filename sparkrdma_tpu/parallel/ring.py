@@ -54,7 +54,8 @@ def supports_pallas_partition_id() -> bool:
     pallas ring test.  Probed ONCE by compiling a miniature (D=2,
     8×128) replica of exactly that pattern; callers route to the
     data-carried device-index fallback when it answers False.  A
-    1-device process has no SPMD partitioning to trip — True."""
+    1-device process has no SPMD partitioning to trip — True.  On a
+    TPU backend the pattern must compile: a failure there raises."""
     if len(jax.devices()) < 2:
         return True
     from sparkrdma_tpu.ops.attention import block_attention
@@ -83,11 +84,15 @@ def supports_pallas_partition_id() -> bool:
     # 8×128: lane-aligned so the probe also compiles on real TPU
     # backends (where it should answer True, keeping the native path)
     x = jnp.zeros((2, 8, 128), jnp.float32)
-    try:
-        jax.jit(mapped)(x).block_until_ready()
+    probe = jax.jit(mapped)
+    if jax.default_backend() == "tpu":
+        probe(x).block_until_ready()
         return True
+    try:
+        probe(x).block_until_ready()
     except Exception:
         return False
+    return True
 
 
 @functools.lru_cache(maxsize=32)

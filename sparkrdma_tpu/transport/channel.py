@@ -66,7 +66,7 @@ _FATAL_PREFIX = "FATAL:"
 
 def encode_remote_error(err: BaseException) -> str:
     """Serve-side error -> status-frame reason string.  Fatal errors
-    carry a classification prefix so the requester's taxonomy survives
+    carry a classification prefix so the requester's classification survives
     the wire without a frame change."""
     reason = str(err)
     if not is_transient(err) and isinstance(err, TransportError):

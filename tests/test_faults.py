@@ -310,7 +310,7 @@ def test_stripe_health_disabled_at_failures_zero():
     assert not sh.demoted()
 
 
-# -- error taxonomy -----------------------------------------------------------
+# -- error classification -----------------------------------------------------------
 
 
 def test_transient_classification_and_wire_roundtrip():

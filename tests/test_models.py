@@ -43,6 +43,39 @@ def test_terasort_skewed_overflow_retry(mesh, devices):
     np.testing.assert_array_equal(sk, np.sort(keys))
 
 
+@pytest.mark.parametrize("n_local", [1 << 10, 1 << 21, 1 << 24])
+def test_terasort_sample_positions_exact_at_chip_sizes(n_local):
+    """The splitter sample sits at the exact local quantiles i*n/S at
+    every size a chip holds.  In int32, i*n wrapped from n_local = 2^21
+    (S = 1024): on four v5e chips at 2^24 records each the skewed
+    splitters overflowed every bucket and the capacity retries ran out
+    of HBM (chip_smoke.py --chips 4, PR 21)."""
+    from sparkrdma_tpu.models.terasort import _sample_positions
+
+    S = 1024
+    ref = [i * n_local // S for i in range(S)]
+    np.testing.assert_array_equal(_sample_positions(n_local, S), ref)
+
+
+def test_wide_rows_ride_their_keys(mesh, devices):
+    """HiBench-shaped rows ([n, W] payload) through the host-facing
+    sort: keys sorted, and each payload row still on its key."""
+    sorter = TeraSorter(mesh)
+    rng = np.random.default_rng(3)
+    n = 8 * 4096
+    keys = rng.integers(0, 1 << 12, size=n, dtype=np.int32)  # ties
+    pay = rng.integers(0, 1 << 31, size=(n, 24), dtype=np.int32)
+    pay[:, 0] = np.arange(n)
+    sk, sp = sorter.sort(keys, pay)
+    perm = sp[:, 0]
+    np.testing.assert_array_equal(np.sort(perm), np.arange(n))
+    np.testing.assert_array_equal(sk, np.sort(keys))
+    np.testing.assert_array_equal(keys[perm], sk)
+    np.testing.assert_array_equal(pay[perm], sp)
+    with pytest.raises(ValueError, match="divisible"):
+        sorter.sort(keys[:-1], pay[:-1])
+
+
 def test_terasort_ragged_length_and_empty(mesh, devices):
     sorter = TeraSorter(mesh)
     keys = np.array([5, 3, 9], dtype=np.int32)  # not divisible by 8

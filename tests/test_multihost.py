@@ -15,17 +15,22 @@ from sparkrdma_tpu.parallel.multihost import (
     supports_multiprocess_collectives,
 )
 
-# Collection-time gate (the supports_pallas_partition_id precedent):
-# the workers strip the harness's JAX_PLATFORMS/XLA_FLAGS pins and get
-# jax's real default backend — on a CPU-only host that backend cannot
-# run cross-process collectives, so these tests skip with the reason
-# spelled out instead of failing 150-240s into a doomed rendezvous.
-pytestmark = pytest.mark.skipif(
-    not supports_multiprocess_collectives(),
-    reason="default jax backend has no multiprocess collectives "
-    "(CPU backend: 'Multiprocess computations aren't implemented') — "
-    "needs a real TPU/GPU multi-controller runtime",
-)
+
+@pytest.fixture(autouse=True, scope="module")
+def _multiprocess_collectives():
+    """The workers strip the harness's JAX_PLATFORMS/XLA_FLAGS pins and
+    get jax's real default backend — on a CPU-only host that backend
+    cannot run cross-process collectives, so these tests skip with the
+    reason spelled out instead of failing 150-240s into a doomed
+    rendezvous.  Probed here, not at import: the probe starts a child
+    process that initializes the default backend."""
+    if not supports_multiprocess_collectives():
+        pytest.skip(
+            "default jax backend has no multiprocess collectives "
+            "(CPU backend: 'Multiprocess computations aren't "
+            "implemented') — needs a real TPU/GPU multi-controller "
+            "runtime"
+        )
 
 
 def _free_port() -> int:
