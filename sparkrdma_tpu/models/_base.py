@@ -6,20 +6,45 @@ a static capacity; true counts travel with the exchange; if any bucket's
 true count exceeded capacity the host re-runs the step with doubled
 capacity (the SPMD inversion of the reference's maxAggBlock fetch cap,
 SURVEY.md §7 hard parts).
+
+The host driver opens a span (``utils/trace.py``) at each of its
+boundaries, ``shuffle.device.<phase>``: ``pad`` (host preparation),
+``place`` (``jax.device_put``), ``attempt`` (one pass of the overflow
+loop) holding ``sync`` (the wait for the step's bucket fill),
+``fetch`` (the step's outputs to host memory) and ``stitch`` (the
+host-side result).  Each retry ticks ``device_overflow_retries_total``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import math
+import resource
 from typing import Callable, Optional, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from sparkrdma_tpu.metrics import counter
 from sparkrdma_tpu.parallel.mesh import EXCHANGE_AXIS, make_mesh
+from sparkrdma_tpu.utils.trace import get_tracer
 
 MAX_OVERFLOW_RETRIES = 6
+
+
+@contextlib.contextmanager
+def faulting_span(name: str, **args):
+    """``get_tracer().span`` that, while recorded, also sets ``minflt``:
+    the minor page faults the process took inside it (host buffers
+    touched for the first time)."""
+    with get_tracer().span(name, **args) as sp:
+        before = resource.getrusage(resource.RUSAGE_SELF).ru_minflt \
+            if sp.recording else None
+        yield sp
+        if before is not None:
+            sp.set(minflt=resource.getrusage(
+                resource.RUSAGE_SELF).ru_minflt - before)
 
 
 def quantize_padded_length(n: int, d: int) -> int:
@@ -88,68 +113,81 @@ class ExchangeModel:
         cap = int(math.ceil(n_local / self.n_devices * factor))
         return max(8, (cap + 7) // 8 * 8)
 
-    def _retry_with_factor(self, run: Callable[[float], Tuple]):
-        """Call ``run(factor)`` → (outputs, overflowed: bool); re-run
-        with doubled skew factor while any bucket overflowed.  The
-        general form for models with more than one capacity (e.g. the
-        two-sided join)."""
-        factor = self.capacity_factor
-        for _attempt in range(MAX_OVERFLOW_RETRIES):
-            outputs, overflowed = run(factor)
-            if not overflowed:
-                return outputs
-            factor *= 2  # key skew overflowed a bucket: retry bigger
-        raise RuntimeError(
-            f"bucket overflow persisted after {MAX_OVERFLOW_RETRIES} retries"
-        )
-
     def _run_with_overflow_retry(
         self, n_total: int, run: Callable[[int], Tuple]
     ):
         """Call ``run(capacity)`` → (outputs, max_fill); re-run with
-        doubled factor while any bucket overflowed."""
-
-        def attempt(factor: float):
+        doubled skew factor while any bucket overflowed."""
+        tracer = get_tracer()
+        factor = self.capacity_factor
+        for attempt in range(MAX_OVERFLOW_RETRIES):
+            if attempt:
+                # key skew overflowed a bucket: retry bigger
+                counter("device_overflow_retries_total").inc()
+                factor *= 2
             cap = self._capacity(n_total // self.n_devices, factor)
-            outputs, max_fill = run(cap)
-            return outputs, int(np.max(np.asarray(max_fill))) > cap
+            with tracer.span("shuffle.device.attempt", factor=factor,
+                             capacity=cap) as sp:
+                outputs, max_fill = run(cap)
+                with tracer.span("shuffle.device.sync"):
+                    fill = int(np.max(np.asarray(max_fill)))
+                sp.set(max_fill=fill, overflowed=fill > cap)
+            if fill <= cap:
+                return outputs
+        raise RuntimeError(
+            f"bucket overflow persisted after {MAX_OVERFLOW_RETRIES} retries"
+        )
 
-        return self._retry_with_factor(attempt)
+    def _place(self, *cols, sharding=None) -> Tuple:
+        """``jax.device_put`` each host column, shard by shard (never
+        whole on one device), in one ``place`` span."""
+        sharding = sharding or self.sharding
+        with faulting_span("shuffle.device.place",
+                           bytes=sum(c.nbytes for c in cols),
+                           shards=self.n_devices):
+            return tuple(jax.device_put(c, sharding) for c in cols)
 
-    def _run_padded_keyed(self, keys, vals, make_step):
+    def _run_padded_keyed(self, keys, vals, make_step,
+                          result_cols: Optional[int] = None):
         """Shared host driver for keyed-exchange models (wordcount,
         aggregate): pad columns to a multiple of D with a validity
         column, place them on the mesh ONCE, run
         ``make_step(mesh, n_local, capacity)`` under the overflow-retry
-        policy, and hand back per-device host rows.
+        policy, and hand back per-device host rows.  ``vals`` None
+        counts one per key.
 
         The step must return ``(*row_arrays, n_unique[1], max_fill[1])``
         per device.  Returns ``(rows, nu)``: each of ``rows`` reshaped
         to [D, -1] on the host, ``nu`` the int32[D] valid-row counts.
+        ``result_cols``: the leading row columns the caller returns of
+        each valid row (the fetch span's ``result_bytes``); all of them
+        by default.
         """
-        keys = np.asarray(keys)
-        vals = np.asarray(vals)
-        if keys.shape != vals.shape or keys.ndim != 1:
-            raise ValueError("keys/vals must be equal-length 1-D arrays")
-        check_no_silent_truncation(keys=keys, vals=vals)
-        n = keys.shape[0]
-        if n == 0:
-            return None, None
-        D = self.n_devices
-        n_pad = self._padded_length(n) - n
-        valid = np.ones(n + n_pad, np.int32)
-        if n_pad:
-            keys = np.concatenate([keys, np.zeros(n_pad, keys.dtype)])
-            vals = np.concatenate([vals, np.zeros(n_pad, vals.dtype)])
-            valid[n:] = 0
-        # D == 1 with no padding: every slot is real, so the step can
-        # drop the validity operand from its sort (the sort is the
-        # step's whole cost on one chip)
-        fast = D == 1 and n_pad == 0
-        cols = (keys, vals) if fast else (keys, vals, valid)
-        # place once, shard by shard (never whole on one device): only
-        # the capacity changes between overflow retries
-        placed = tuple(jax.device_put(x, self.sharding) for x in cols)
+        tracer = get_tracer()
+        with tracer.span("shuffle.device.pad") as sp:
+            keys = np.asarray(keys)
+            vals = np.ones_like(keys) if vals is None else np.asarray(vals)
+            if keys.shape != vals.shape or keys.ndim != 1:
+                raise ValueError("keys/vals must be equal-length 1-D arrays")
+            check_no_silent_truncation(keys=keys, vals=vals)
+            n = keys.shape[0]
+            if n == 0:
+                return None, None
+            D = self.n_devices
+            n_pad = self._padded_length(n) - n
+            valid = np.ones(n + n_pad, np.int32)
+            if n_pad:
+                keys = np.concatenate([keys, np.zeros(n_pad, keys.dtype)])
+                vals = np.concatenate([vals, np.zeros(n_pad, vals.dtype)])
+                valid[n:] = 0
+            # D == 1 with no padding: every slot is real, so the step
+            # can drop the validity operand from its sort (the sort is
+            # the step's whole cost on one chip)
+            fast = D == 1 and n_pad == 0
+            cols = (keys, vals) if fast else (keys, vals, valid)
+            sp.set(bytes=sum(c.nbytes for c in cols))
+        # place once: only the capacity changes between overflow retries
+        placed = self._place(*cols)
 
         def run(cap):
             step = make_step(
@@ -160,6 +198,11 @@ class ExchangeModel:
             return (rows, n_unique), max_fill
 
         rows, n_unique = self._run_with_overflow_retry(n + n_pad, run)
-        host_rows = [np.asarray(r).reshape(D, -1) for r in rows]
-        nu = np.asarray(n_unique).reshape(-1)
+        with faulting_span("shuffle.device.fetch",
+                           bytes=sum(r.nbytes for r in rows)
+                           + n_unique.nbytes) as sp:
+            host_rows = [np.asarray(r).reshape(D, -1) for r in rows]
+            nu = np.asarray(n_unique).reshape(-1)
+            sp.set(result_bytes=int(nu.sum()) * sum(
+                r.dtype.itemsize for r in host_rows[:result_cols]))
         return host_rows, nu

@@ -277,15 +277,14 @@ class HashJoiner(ExchangeModel):
             for x in (lk, lv, l_valid, rk, rv, r_valid)
         )
 
-        def attempt(factor: float):
+        def run(cap):
             # one capacity for the fused fact+dim stream
-            cap = self._capacity((nl + nr) // D, factor)
             step = make_hash_join_step(self.mesh, nl // D, nr // D, cap)
             sk, spay, fval, found, is_fact, fill = step(*placed)
-            overflowed = int(np.max(np.asarray(fill))) > cap
-            return (sk, spay, fval, found, is_fact), overflowed
+            return (sk, spay, fval, found, is_fact), fill
 
-        sk, spay, fval, found, is_fact = self._retry_with_factor(attempt)
+        sk, spay, fval, found, is_fact = self._run_with_overflow_retry(
+            nl + nr, run)
         return _mask_output(sk, spay, fval, found, is_fact,
                             lk.dtype, lv.dtype, rv.dtype, how)
 

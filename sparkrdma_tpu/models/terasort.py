@@ -36,9 +36,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from sparkrdma_tpu.models._base import ExchangeModel
+from sparkrdma_tpu.models._base import ExchangeModel, faulting_span
 from sparkrdma_tpu.ops.partition import make_range_splitters
 from sparkrdma_tpu.parallel.mesh import EXCHANGE_AXIS
+from sparkrdma_tpu.utils.trace import get_tracer
 
 
 def _sample_positions(n_local: int, sample_size: int) -> np.ndarray:
@@ -258,14 +259,14 @@ def make_wide_sort_step(mesh: Mesh, n_local: int, payload_words: int,
     spec = P(EXCHANGE_AXIS)
     spec2 = P(EXCHANGE_AXIS, None)
 
-    def body(k, p):
+    def terasort_wide_step(k, p):
         sk, sp, n_valid, overflow = _local_sort_wide_step(
             k, p, D, capacity, sample_size
         )
         return sk, sp, n_valid[None], overflow[None]
 
     mapped = jax.shard_map(
-        body, mesh=mesh, in_specs=(spec, spec2),
+        terasort_wide_step, mesh=mesh, in_specs=(spec, spec2),
         out_specs=(spec, spec2, spec, spec),
     )
     return jax.jit(mapped)
@@ -291,7 +292,7 @@ def make_sort_step(
     spec = P(EXCHANGE_AXIS)
 
     if with_validity:
-        def body(k, v, valid):  # local [n_local]
+        def terasort_step(k, v, valid):  # local [n_local]
             sk, sv, n_valid, overflow = _local_sort_step(
                 k, v, valid, D, capacity, sample_size
             )
@@ -299,7 +300,7 @@ def make_sort_step(
 
         in_specs = (spec, spec, spec)
     else:
-        def body(k, v):  # local [n_local]
+        def terasort_step(k, v):  # local [n_local]
             sk, sv, n_valid, overflow = _local_sort_step(
                 k, v, None, D, capacity, sample_size
             )
@@ -308,7 +309,7 @@ def make_sort_step(
         in_specs = (spec, spec)
 
     mapped = jax.shard_map(
-        body, mesh=mesh, in_specs=in_specs,
+        terasort_step, mesh=mesh, in_specs=in_specs,
         out_specs=(spec, spec, spec, spec),
     )
     return jax.jit(mapped)
@@ -389,6 +390,10 @@ class TeraSorter(ExchangeModel):
         ``vals`` of shape [n, W] are wide-record payload rows (the
         HiBench shape) that ride their keys (:meth:`sort_device_wide`)."""
         keys = np.asarray(keys)
+        with get_tracer().span("shuffle.device.sort", rows=keys.size):
+            return self._sort(keys, vals)
+
+    def _sort(self, keys: np.ndarray, vals):
         if vals is None:
             vals = np.zeros_like(keys)
         vals = np.asarray(vals)
@@ -407,18 +412,21 @@ class TeraSorter(ExchangeModel):
         # validity column (NOT by key value), so max-valued real keys
         # are safe
         D = self.n_devices
-        n_pad = self._padded_length(n) - n
-        sentinel = np.array(np.iinfo(keys.dtype).max, keys.dtype)
-        if n_pad:
-            keys = np.concatenate([keys, np.full(n_pad, sentinel, keys.dtype)])
-            vals = np.concatenate([vals, np.zeros(n_pad, vals.dtype)])
-            valid = np.ones(n + n_pad, np.int32)
-            valid[n:] = 0
-            jval = jax.device_put(valid, self.sharding)
-        else:
-            jval = None  # fast path: no padding column needed
-        jk = jax.device_put(keys, self.sharding)
-        jv = jax.device_put(vals, self.sharding)
+        with get_tracer().span("shuffle.device.pad") as sp:
+            n_pad = self._padded_length(n) - n
+            sentinel = np.array(np.iinfo(keys.dtype).max, keys.dtype)
+            cols = (keys, vals)
+            if n_pad:
+                keys = np.concatenate(
+                    [keys, np.full(n_pad, sentinel, keys.dtype)])
+                vals = np.concatenate([vals, np.zeros(n_pad, vals.dtype)])
+                valid = np.ones(n + n_pad, np.int32)
+                valid[n:] = 0
+                cols = (valid, keys, vals)
+            sp.set(bytes=sum(c.nbytes for c in cols))
+        *jval, jk, jv = self._place(*cols)
+        # no padding: the fast path needs no validity column
+        jval = jval[0] if jval else None
 
         def run(cap):
             (sk, sv, n_valid, max_fill), _ = self.sort_device(
@@ -427,14 +435,27 @@ class TeraSorter(ExchangeModel):
             return (sk, sv, n_valid), max_fill
 
         sk, sv, n_valid = self._run_with_overflow_retry(n + n_pad, run)
+        sk_h, sv_h, nv = self._fetch(sk, sv, n_valid, keys.itemsize
+                                     + vals.itemsize)
         # stitch: per-device sorted runs, trimmed to their valid counts
         # (padding always sorts to each run's tail via the validity key)
-        sk_h = np.asarray(sk).reshape(D, -1)
-        sv_h = np.asarray(sv).reshape(D, -1)
-        nv = np.asarray(n_valid).reshape(-1)
-        out_k = np.concatenate([sk_h[d, : nv[d]] for d in range(D)])
-        out_v = np.concatenate([sv_h[d, : nv[d]] for d in range(D)])
+        with get_tracer().span("shuffle.device.stitch"):
+            out_k = np.concatenate([sk_h[d, : nv[d]] for d in range(D)])
+            out_v = np.concatenate([sv_h[d, : nv[d]] for d in range(D)])
         return out_k, out_v
+
+    def _fetch(self, sk, sp, n_valid, row_bytes: int):
+        """The step's outputs to host memory, in one ``fetch`` span:
+        per-device runs of keys and values and their valid counts."""
+        D = self.n_devices
+        with faulting_span("shuffle.device.fetch",
+                           bytes=sk.nbytes + sp.nbytes
+                           + n_valid.nbytes) as span:
+            sk_h = np.asarray(sk).reshape(D, -1)
+            sp_h = np.asarray(sp).reshape(D, -1, *sp.shape[1:])
+            nv = np.asarray(n_valid).reshape(-1)
+            span.set(result_bytes=row_bytes * int(nv.sum()))
+        return sk_h, sp_h, nv
 
     def _sort_wide(self, keys: np.ndarray, payload: np.ndarray):
         """Host-facing wide-record sort: rows are placed shard by shard
@@ -448,9 +469,9 @@ class TeraSorter(ExchangeModel):
             )
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        jk = jax.device_put(keys, self.sharding)
-        jp = jax.device_put(
-            payload, NamedSharding(self.mesh, P(EXCHANGE_AXIS, None))
+        (jk,) = self._place(keys)
+        (jp,) = self._place(
+            payload, sharding=NamedSharding(self.mesh, P(EXCHANGE_AXIS, None))
         )
 
         def run(cap):
@@ -460,10 +481,10 @@ class TeraSorter(ExchangeModel):
             return (sk, sp, n_valid), max_fill
 
         sk, sp, n_valid = self._run_with_overflow_retry(n, run)
-        sk_h = np.asarray(sk).reshape(D, -1)
-        sp_h = np.asarray(sp).reshape(D, -1, payload.shape[1])
-        nv = np.asarray(n_valid).reshape(-1)
-        return (
-            np.concatenate([sk_h[d, : nv[d]] for d in range(D)]),
-            np.concatenate([sp_h[d, : nv[d]] for d in range(D)]),
-        )
+        sk_h, sp_h, nv = self._fetch(
+            sk, sp, n_valid, keys.itemsize + payload[0].nbytes)
+        with get_tracer().span("shuffle.device.stitch"):
+            return (
+                np.concatenate([sk_h[d, : nv[d]] for d in range(D)]),
+                np.concatenate([sp_h[d, : nv[d]] for d in range(D)]),
+            )

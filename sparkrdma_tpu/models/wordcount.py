@@ -24,6 +24,7 @@ from sparkrdma_tpu.models._base import ExchangeModel
 from sparkrdma_tpu.ops.exchange import hash_exchange
 from sparkrdma_tpu.ops.segment import reduce_by_key_local
 from sparkrdma_tpu.parallel.mesh import EXCHANGE_AXIS
+from sparkrdma_tpu.utils.trace import get_tracer
 
 
 @functools.lru_cache(maxsize=16)
@@ -43,17 +44,17 @@ def make_count_step(mesh: Mesh, n_local: int, capacity: int,
                 "a real exchange need the validity column)"
             )
 
-        def body_nv(k, v):  # local [n_local], all slots real
+        def wordcount_step(k, v):  # local [n_local], all slots real
             uniq, sums, cnts, n_unique = reduce_by_key_local(k, v, None)
             return uniq, sums, cnts, n_unique[None], jnp.zeros(1, jnp.int32)
 
         mapped = jax.shard_map(
-            body_nv, mesh=mesh, in_specs=(spec, spec),
+            wordcount_step, mesh=mesh, in_specs=(spec, spec),
             out_specs=(spec,) * 5,
         )
         return jax.jit(mapped)
 
-    def body(k, v, valid):  # local [n_local]
+    def wordcount_step(k, v, valid):  # local [n_local]
         # (hash_exchange is the identity for D == 1 — no padded sorts)
         flat_k, flat_v, flat_m, max_fill = hash_exchange(
             k, v, valid, D, capacity
@@ -69,7 +70,7 @@ def make_count_step(mesh: Mesh, n_local: int, capacity: int,
         return uniq, sums, cnts, n_unique[None], max_fill[None]
 
     mapped = jax.shard_map(
-        body, mesh=mesh, in_specs=(spec, spec, spec),
+        wordcount_step, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=(spec,) * 5,
     )
     return jax.jit(mapped)
@@ -107,15 +108,20 @@ class WordCounter(ExchangeModel):
         """Totals wrap in the value dtype on overflow (JVM Int/Long
         parity — Spark's reduceByKey(_+_) over Int wraps identically)."""
         keys = np.asarray(keys)
-        vals = np.ones_like(keys) if vals is None else np.asarray(vals)
-        rows, nu = self._run_padded_keyed(keys, vals, make_count_step)
-        if rows is None:
-            return {}
-        uniq_h, sums_h, counts_h = rows
-        out: Dict[int, int] = {}
-        for d in range(self.n_devices):
-            # results live at run-end positions: extract by counts > 0
-            mask = counts_h[d] > 0
-            for k, s in zip(uniq_h[d][mask], sums_h[d][mask]):
-                out[int(k)] = int(s)
-        return out
+        tracer = get_tracer()
+        with tracer.span("shuffle.device.count", rows=keys.size):
+            # a returned entry is (key, total): the first two row columns
+            rows, nu = self._run_padded_keyed(keys, vals, make_count_step,
+                                              result_cols=2)
+            if rows is None:
+                return {}
+            uniq_h, sums_h, counts_h = rows
+            out: Dict[int, int] = {}
+            with tracer.span("shuffle.device.stitch"):
+                for d in range(self.n_devices):
+                    # results live at run-end positions: extract by
+                    # counts > 0
+                    mask = counts_h[d] > 0
+                    for k, s in zip(uniq_h[d][mask], sums_h[d][mask]):
+                        out[int(k)] = int(s)
+            return out

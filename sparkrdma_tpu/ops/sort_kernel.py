@@ -19,8 +19,8 @@ Blocks default to 512 rows: at 1024 the network's temporaries
 exceed the 16 MiB of scoped VMEM and the TPU compiler refuses the
 kernel (tests/test_chip_compile.py compiles it for v5e).  Interpret
 mode pins its semantics (tests).  Nothing dispatches to it by default
-— call sites must opt in after ``tools/profile_tpu_sort.py`` shows it
-beating ``lax.sort`` on chip.
+— call sites must opt in after the chip benchmark (``shufflebench/``)
+shows it beating ``lax.sort`` on chip.
 """
 
 from __future__ import annotations
