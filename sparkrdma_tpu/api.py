@@ -217,7 +217,8 @@ class TpuShuffleContext:
 
     # -- device-native workloads (the MXU/ICI plane) ------------------------
     def device_sort(self, keys, vals=None, mesh=None):
-        """Global sortByKey on the device mesh (TeraSort path)."""
+        """Global sortByKey on the device mesh (TeraSort path).  Returns
+        read-only (keys, vals) host arrays (``TeraSorter.sort``)."""
         from sparkrdma_tpu.models.terasort import TeraSorter
 
         return TeraSorter(mesh).sort(keys, vals)

@@ -13,6 +13,9 @@ boundaries, ``shuffle.device.<phase>``: ``pad`` (host preparation),
 loop) holding ``sync`` (the wait for the step's bucket fill),
 ``fetch`` (the step's outputs to host memory) and ``stitch`` (the
 host-side result).  Each retry ticks ``device_overflow_retries_total``.
+
+Outputs come to the host shard by shard (:meth:`ExchangeModel._fetch_runs`):
+one host buffer a device, never a global host array.
 """
 
 from __future__ import annotations
@@ -20,7 +23,7 @@ from __future__ import annotations
 import contextlib
 import math
 import resource
-from typing import Callable, Optional, Tuple
+from typing import Callable, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -130,7 +133,8 @@ class ExchangeModel:
                              capacity=cap) as sp:
                 outputs, max_fill = run(cap)
                 with tracer.span("shuffle.device.sync"):
-                    fill = int(np.max(np.asarray(max_fill)))
+                    (fills,) = self._fetch_runs(max_fill)
+                    fill = max(int(f.max()) for f in fills)
                 sp.set(max_fill=fill, overflowed=fill > cap)
             if fill <= cap:
                 return outputs
@@ -147,6 +151,35 @@ class ExchangeModel:
                            shards=self.n_devices):
             return tuple(jax.device_put(c, sharding) for c in cols)
 
+    @staticmethod
+    def _fetch_runs(*arrays) -> List[List[np.ndarray]]:
+        """Each array's per-device shards as read-only host arrays, in
+        mesh order.  Every shard's copy starts before the first is
+        waited for.  ``np.asarray`` of a sharded array would also copy
+        every shard into a fresh global host array; this never builds
+        one, so one device's run is one host buffer."""
+        shards = [[s.data for s in sorted(
+            a.addressable_shards, key=lambda s: s.index[0].start or 0)]
+            for a in arrays]
+        for runs in shards:
+            for x in runs:
+                x.copy_to_host_async()
+        return [[np.asarray(x) for x in runs] for runs in shards]
+
+    def _fetch(self, *outputs, row_bytes: int):
+        """The step's outputs to host memory, in one ``fetch`` span.
+        ``outputs`` end with the per-device valid-row counts; returns
+        (per output but the counts, its D per-device runs; the counts
+        as int[D]).  ``row_bytes``: what the caller returns of each
+        valid row (the span's ``result_bytes``)."""
+        with faulting_span("shuffle.device.fetch",
+                           bytes=sum(o.nbytes for o in outputs),
+                           shards=self.n_devices) as sp:
+            *runs, counts = self._fetch_runs(*outputs)
+            counts = np.concatenate(counts)
+            sp.set(result_bytes=row_bytes * int(counts.sum()))
+        return runs, counts
+
     def _run_padded_keyed(self, keys, vals, make_step,
                           result_cols: Optional[int] = None):
         """Shared host driver for keyed-exchange models (wordcount,
@@ -157,8 +190,9 @@ class ExchangeModel:
         counts one per key.
 
         The step must return ``(*row_arrays, n_unique[1], max_fill[1])``
-        per device.  Returns ``(rows, nu)``: each of ``rows`` reshaped
-        to [D, -1] on the host, ``nu`` the int32[D] valid-row counts.
+        per device.  Returns ``(rows, nu)``: each of ``rows`` a list of
+        its D per-device host runs (``rows[i][d]``, read-only), ``nu``
+        the int32[D] valid-row counts.
         ``result_cols``: the leading row columns the caller returns of
         each valid row (the fetch span's ``result_bytes``); all of them
         by default.
@@ -198,11 +232,5 @@ class ExchangeModel:
             return (rows, n_unique), max_fill
 
         rows, n_unique = self._run_with_overflow_retry(n + n_pad, run)
-        with faulting_span("shuffle.device.fetch",
-                           bytes=sum(r.nbytes for r in rows)
-                           + n_unique.nbytes) as sp:
-            host_rows = [np.asarray(r).reshape(D, -1) for r in rows]
-            nu = np.asarray(n_unique).reshape(-1)
-            sp.set(result_bytes=int(nu.sum()) * sum(
-                r.dtype.itemsize for r in host_rows[:result_cols]))
-        return host_rows, nu
+        return self._fetch(*rows, n_unique, row_bytes=sum(
+            r.dtype.itemsize for r in rows[:result_cols]))

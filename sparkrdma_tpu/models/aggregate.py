@@ -108,8 +108,8 @@ class KeyedAggregator(ExchangeModel):
             # results live at run-end positions: extract by counts > 0
             (idx,) = (counts_h[d] > 0).nonzero()
             for i in idx:
-                out[int(uniq_h[d, i])] = KeyStats(
-                    int(sums_h[d, i]), int(counts_h[d, i]),
-                    int(mins_h[d, i]), int(maxs_h[d, i]),
+                out[int(uniq_h[d][i])] = KeyStats(
+                    int(sums_h[d][i]), int(counts_h[d][i]),
+                    int(mins_h[d][i]), int(maxs_h[d][i]),
                 )
         return out

@@ -42,6 +42,12 @@ from sparkrdma_tpu.parallel.mesh import EXCHANGE_AXIS
 from sparkrdma_tpu.utils.trace import get_tracer
 
 
+def _read_only(*arrays: np.ndarray) -> Tuple[np.ndarray, ...]:
+    for a in arrays:
+        a.flags.writeable = False
+    return arrays
+
+
 def _sample_positions(n_local: int, sample_size: int) -> np.ndarray:
     """Exact local quantile positions i*n/S, computed on the host in
     64 bits: in int32, i*n overflows once S*n reaches 2^31 (n_local
@@ -272,6 +278,68 @@ def make_wide_sort_step(mesh: Mesh, n_local: int, payload_words: int,
     return jax.jit(mapped)
 
 
+PIECES = 16  # equal pieces a device's payload words are placed in
+PIECE_GROUP = 128  # their rows are a multiple of this; the short last's not
+
+
+def piece_rows(n_local: int) -> Tuple[int, ...]:
+    """Rows of each piece a device's payload is placed in: PIECES equal
+    runs of a multiple of PIECE_GROUP rows, then what is left (fewer
+    than PIECES * PIECE_GROUP rows), if anything."""
+    c = n_local // PIECES // PIECE_GROUP * PIECE_GROUP
+    tail = n_local - PIECES * c
+    return (c,) * PIECES * (c > 0) + (tail,) * (tail > 0)
+
+
+def _rows_from_words(*pieces, width: int):
+    """Per-device body (runs under shard_map): pieces of [rows*width]
+    row-major words, in row order → [n, width] rows.
+
+    XLA:TPU lays a narrow [n, width] array out column-major (n minor,
+    tiled), so rows handed to ``device_put`` as they sit in host memory
+    are first transposed on the host by the runtime.  Placing flat
+    words is a plain copy (in pieces, which the runtime copies side by
+    side); this transposes them on the chip instead, a piece at a time:
+    through [rows/128, 128*width] and [width, rows] views, whose minor
+    dimensions are multiples of 128, so nothing is padded out to 128
+    lanes, and a piece bounds what the transpose holds besides its
+    input and output."""
+    g = PIECE_GROUP
+    n = sum(p.shape[0] for p in pieces) // width
+    out = jax.lax.pcast(
+        jnp.zeros((width, n), pieces[0].dtype), EXCHANGE_AXIS, to="varying"
+    )
+    start = 0
+    for p in pieces:
+        c = p.shape[0] // width
+        if c % g:  # the short last piece: a padded [c, 128] is small
+            cols = p.reshape(c, width).T
+        else:
+            cols = (p.reshape(c // g, g * width).T
+                    .reshape(g, width, c // g).transpose(1, 2, 0)
+                    .reshape(width, c))
+        out = jax.lax.dynamic_update_slice(out, cols, (0, start))
+        start += c
+    return out.T
+
+
+@functools.lru_cache(maxsize=16)
+def make_rows_from_words(mesh: Mesh, width: int, pieces: int):
+    """Jitted fn(*pieces, each [D*rows*width] sharded on the mesh) →
+    rows [D*n_local, width], each device's rows from its own pieces of
+    words (:func:`piece_rows`)."""
+    from jax.sharding import PartitionSpec as P
+
+    def terasort_rows_from_words(*words):
+        return _rows_from_words(*words, width=width)
+
+    return jax.jit(jax.shard_map(
+        terasort_rows_from_words, mesh=mesh,
+        in_specs=(P(EXCHANGE_AXIS),) * pieces,
+        out_specs=P(EXCHANGE_AXIS, None),
+    ))
+
+
 @functools.lru_cache(maxsize=16)
 def make_sort_step(
     mesh: Mesh, n_local: int, capacity: int, sample_size: int = 1024,
@@ -388,7 +456,12 @@ class TeraSorter(ExchangeModel):
     def sort(self, keys, vals=None) -> Tuple[np.ndarray, np.ndarray]:
         """Full host-facing sortByKey: returns (sorted_keys, sorted_vals).
         ``vals`` of shape [n, W] are wide-record payload rows (the
-        HiBench shape) that ride their keys (:meth:`sort_device_wide`)."""
+        HiBench shape) that ride their keys (:meth:`sort_device_wide`).
+
+        The returned arrays are read-only: on one device they are views
+        of the buffers the devices' runs were fetched into (which hold
+        the capacity-padded run until the result is dropped), so copy
+        them to write."""
         keys = np.asarray(keys)
         with get_tracer().span("shuffle.device.sort", rows=keys.size):
             return self._sort(keys, vals)
@@ -404,14 +477,13 @@ class TeraSorter(ExchangeModel):
             )
         n = keys.shape[0]
         if n == 0:
-            return keys.copy(), vals.copy()
+            return _read_only(keys.copy(), vals.copy())
         if vals.ndim == 2:
             return self._sort_wide(keys, vals)
         # pad to a multiple of D on the compile-shape ladder
         # (_base.quantize_padded_length); padding is tracked by the
         # validity column (NOT by key value), so max-valued real keys
         # are safe
-        D = self.n_devices
         with get_tracer().span("shuffle.device.pad") as sp:
             n_pad = self._padded_length(n) - n
             sentinel = np.array(np.iinfo(keys.dtype).max, keys.dtype)
@@ -435,44 +507,40 @@ class TeraSorter(ExchangeModel):
             return (sk, sv, n_valid), max_fill
 
         sk, sv, n_valid = self._run_with_overflow_retry(n + n_pad, run)
-        sk_h, sv_h, nv = self._fetch(sk, sv, n_valid, keys.itemsize
-                                     + vals.itemsize)
-        # stitch: per-device sorted runs, trimmed to their valid counts
-        # (padding always sorts to each run's tail via the validity key)
-        with get_tracer().span("shuffle.device.stitch"):
-            out_k = np.concatenate([sk_h[d, : nv[d]] for d in range(D)])
-            out_v = np.concatenate([sv_h[d, : nv[d]] for d in range(D)])
-        return out_k, out_v
+        # padding always sorts to each run's tail via the validity key
+        return self._stitch(*self._fetch(
+            sk, sv, n_valid, row_bytes=keys.itemsize + vals.itemsize))
 
-    def _fetch(self, sk, sp, n_valid, row_bytes: int):
-        """The step's outputs to host memory, in one ``fetch`` span:
-        per-device runs of keys and values and their valid counts."""
+    def _stitch(self, runs, nv):
+        """The sorted result from the per-device runs, each trimmed to
+        its valid count: on one device a view of the fetched run, on
+        several one copy of the valid rows.  Read-only either way."""
         D = self.n_devices
-        with faulting_span("shuffle.device.fetch",
-                           bytes=sk.nbytes + sp.nbytes
-                           + n_valid.nbytes) as span:
-            sk_h = np.asarray(sk).reshape(D, -1)
-            sp_h = np.asarray(sp).reshape(D, -1, *sp.shape[1:])
-            nv = np.asarray(n_valid).reshape(-1)
-            span.set(result_bytes=row_bytes * int(nv.sum()))
-        return sk_h, sp_h, nv
+        with get_tracer().span("shuffle.device.stitch") as sp:
+            if D == 1:
+                out = tuple(r[0][: nv[0]] for r in runs)
+                copied = 0
+            else:
+                out = _read_only(*(
+                    np.concatenate([r[d][: nv[d]] for d in range(D)])
+                    for r in runs))
+                copied = sum(o.nbytes for o in out)
+            sp.set(bytes=copied)
+        return out
 
     def _sort_wide(self, keys: np.ndarray, payload: np.ndarray):
-        """Host-facing wide-record sort: rows are placed shard by shard
-        once, sorted on the mesh under the overflow-retry policy, and
-        stitched from the per-device runs.  The wide step carries no
-        validity column, so the length must divide D."""
+        """Host-facing wide-record sort: rows are placed once, as flat
+        words (:meth:`_place_rows`), sorted on the mesh under the
+        overflow-retry policy, and stitched from the per-device runs.
+        The wide step carries no validity column, so the length must
+        divide D."""
         n, D = keys.shape[0], self.n_devices
         if n % D:
             raise ValueError(
                 f"wide rows: length {n} not divisible by D={D}"
             )
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         (jk,) = self._place(keys)
-        (jp,) = self._place(
-            payload, sharding=NamedSharding(self.mesh, P(EXCHANGE_AXIS, None))
-        )
+        jp = self._place_rows(payload)
 
         def run(cap):
             (sk, sp, n_valid, max_fill), _ = self.sort_device_wide(
@@ -481,10 +549,25 @@ class TeraSorter(ExchangeModel):
             return (sk, sp, n_valid), max_fill
 
         sk, sp, n_valid = self._run_with_overflow_retry(n, run)
-        sk_h, sp_h, nv = self._fetch(
-            sk, sp, n_valid, keys.itemsize + payload[0].nbytes)
-        with get_tracer().span("shuffle.device.stitch"):
-            return (
-                np.concatenate([sk_h[d, : nv[d]] for d in range(D)]),
-                np.concatenate([sp_h[d, : nv[d]] for d in range(D)]),
-            )
+        return self._stitch(*self._fetch(
+            sk, sp, n_valid, row_bytes=keys.itemsize + payload[0].nbytes))
+
+    def _place_rows(self, rows: np.ndarray) -> jax.Array:
+        """Place [n, W] rows, in one ``place`` span, as their row-major
+        words: each device's rows in pieces (:func:`piece_rows`), every
+        copy started before any is waited for, then made rows on the
+        mesh (:func:`make_rows_from_words`).  The span waits for the
+        rows, so the words are freed before the step allocates."""
+        D, W = self.n_devices, rows.shape[1]
+        words = rows.reshape(D, -1)  # device d's rows, row-major
+        with faulting_span("shuffle.device.place", bytes=rows.nbytes,
+                           shards=D):
+            placed, lo = [], 0
+            for c in piece_rows(rows.shape[0] // D):
+                placed.append(jax.make_array_from_callback(
+                    (D * c * W,), self.sharding,
+                    lambda idx, lo=lo, c=c: words[
+                        (idx[0].start or 0) // (c * W), lo:lo + c * W]))
+                lo += c * W
+            return make_rows_from_words(self.mesh, W, len(placed))(
+                *placed).block_until_ready()

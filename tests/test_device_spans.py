@@ -125,6 +125,11 @@ def test_device_sort_wide_rows_emit_the_driver_spans(tmp_path, devices, d):
     # one valid count
     assert fetch[3]["bytes"] == d * d * cap * 4 * (1 + 24) + 4 * d
     assert fetch[3]["result_bytes"] == sk.nbytes + sp.nbytes
+    assert fetch[3]["shards"] == d
+    # one device: the result is a view of the fetched run; several: one
+    # copy of the valid rows
+    (stitch,) = named(events, DEVICE + "stitch")
+    assert stitch[3]["bytes"] == (0 if d == 1 else sk.nbytes + sp.nbytes)
 
 
 def test_device_sort_padded_keys_emit_pad_and_place(tmp_path, devices):
@@ -144,8 +149,11 @@ def test_device_sort_padded_keys_emit_pad_and_place(tmp_path, devices):
     assert pad[2] <= place[1]
     # keys, values and the validity column, padded to 4096 slots
     assert pad[3]["bytes"] == place[3]["bytes"] == 3 * 4 * 4096
-    (fetch,) = named(events, DEVICE + "fetch")
+    (fetch,), (stitch,) = named(events, DEVICE + "fetch"), named(
+        events, DEVICE + "stitch")
     assert fetch[3]["result_bytes"] == sk.nbytes + sv.nbytes
+    assert fetch[3]["shards"] == 4
+    assert stitch[3]["bytes"] == sk.nbytes + sv.nbytes
 
 
 @pytest.mark.parametrize("d", [1, 4])
@@ -174,6 +182,7 @@ def test_device_count_emits_the_driver_spans(tmp_path, devices, d):
         d * d * attempt[3]["capacity"])
     assert fetch[3]["bytes"] == 3 * 4 * slots + 4 * d
     assert fetch[3]["result_bytes"] == 8 * len(out)
+    assert fetch[3]["shards"] == d
 
 
 def test_forced_overflow_retries_and_ticks_the_counter(tmp_path, devices):

@@ -730,3 +730,134 @@ def test_terasort_wide_records_match_numpy(devices):
         np.testing.assert_array_equal(
             payload[order_in], out_p[order_out]
         )
+
+
+def no_global_host_array(monkeypatch):
+    """Make ``np.asarray`` of a sharded (not fully replicated) device
+    array raise: JAX would assemble it into one global host array."""
+    from jax._src.array import ArrayImpl
+
+    value = ArrayImpl._value
+
+    def whole_or_raise(self):
+        if not self.is_fully_replicated:
+            raise AssertionError(f"global host array of {self.shape}")
+        return value.fget(self)
+
+    monkeypatch.setattr(ArrayImpl, "_value", property(whole_or_raise))
+
+
+@pytest.mark.parametrize("d", [1, 4])
+@pytest.mark.parametrize("wide", [True, False])
+def test_terasort_exact_from_per_device_runs(devices, monkeypatch, d, wide):
+    """The sort is exact from the devices' runs alone, fetched shard by
+    shard (no global host array); its result is read-only and stays as
+    it is while a second sort of the same shape runs."""
+    no_global_host_array(monkeypatch)
+    sorter = TeraSorter(make_mesh(d))
+    rng = np.random.default_rng(40 + d)
+    # wide rows must divide D; narrow rows sit off the shape ladder
+    n = 4096 if wide else 4001
+
+    def job():
+        keys = rng.integers(-50, 50, n, dtype=np.int32)  # many ties
+        rid = np.arange(n, dtype=np.int32)
+        vals = rng.integers(0, 1 << 30, (n, 24), dtype=np.int32) \
+            if wide else rid
+        if wide:
+            vals[:, 0] = rid
+        return keys, vals, sorter.sort(keys, vals)
+
+    keys, vals, (sk, sv) = job()
+    ids = sv[:, 0] if wide else sv
+    np.testing.assert_array_equal(sk, np.sort(keys))
+    np.testing.assert_array_equal(np.sort(ids), np.arange(n))
+    np.testing.assert_array_equal(keys[ids], sk)
+    np.testing.assert_array_equal(vals[ids], sv)
+    for out in (sk, sv):
+        assert not out.flags.writeable
+        with pytest.raises(ValueError, match="read-only"):
+            out[0] = 0
+    first = sk.copy(), sv.copy()
+    job()
+    np.testing.assert_array_equal(sk, first[0])
+    np.testing.assert_array_equal(sv, first[1])
+    empty = sorter.sort(keys[:0], vals[:0])
+    assert [e.shape[0] for e in empty] == [0, 0]
+    assert not any(e.flags.writeable for e in empty)
+
+
+@pytest.mark.parametrize("model", ["count", "aggregate", "top_k"])
+def test_keyed_models_exact_from_per_device_runs(devices, monkeypatch,
+                                                 model):
+    """The keyed models stitch their result from the per-device runs
+    at D == 4, fetched shard by shard: no global host array."""
+    from sparkrdma_tpu.models import KeyedAggregator
+    from sparkrdma_tpu.models.topk import GroupedTopK
+
+    no_global_host_array(monkeypatch)
+    m4 = make_mesh(4)
+    rng = np.random.default_rng(61)
+    keys = rng.integers(0, 300, 20_011, dtype=np.int32)
+    vals = rng.integers(-1000, 1000, 20_011, dtype=np.int32)
+    groups = {int(k): vals[keys == k] for k in np.unique(keys)}
+    if model == "count":
+        assert WordCounter(m4).count(keys, vals) == {
+            k: int(v.sum()) for k, v in groups.items()}
+    elif model == "aggregate":
+        stats = KeyedAggregator(m4).aggregate(keys, vals)
+        assert {k: tuple(s) for k, s in stats.items()} == {
+            k: (int(v.sum()), len(v), int(v.min()), int(v.max()))
+            for k, v in groups.items()}
+    else:
+        assert GroupedTopK(m4).top_k(keys, vals, 3) == {
+            k: np.sort(v)[::-1][:3].tolist() for k, v in groups.items()}
+
+
+@pytest.mark.parametrize("d", [1, 4])
+@pytest.mark.parametrize("n_local,width", [
+    (1, 24), (127, 24), (128, 24), (129, 24), (2048, 24), (5003, 24),
+    (40_000, 24), (3000, 3),
+])
+def test_placed_rows_are_each_devices_own_rows(devices, d, n_local,
+                                                 width):
+    """The payload placed as flat words, in pieces, and made rows on
+    the mesh gives each device its own rows: whole pieces, a short last
+    piece, and a short last piece alone."""
+    mesh = make_mesh(d)
+    rng = np.random.default_rng(n_local + width)
+    rows = rng.integers(-(1 << 31), 1 << 31, (d * n_local, width),
+                        dtype=np.int64).astype(np.int32)
+    out = TeraSorter(mesh)._place_rows(rows)
+    assert out.shape == rows.shape
+    for s in out.addressable_shards:
+        assert s.data.shape == (n_local, width)
+    np.testing.assert_array_equal(np.asarray(out), rows)
+
+
+@pytest.mark.parametrize("d", [1, 4])
+def test_wide_sort_places_host_rows_as_flat_words(devices, monkeypatch, d):
+    """No 2-D host array is handed to the runtime: the chip lays [n, W]
+    rows out column-major, so placing them whole would transpose them
+    on the host.  Every word of the payload is copied once."""
+    import jax
+    from jax._src.interpreters import pxla
+
+    placed = []
+    put = pxla.batched_device_put
+
+    def spy(aval, sharding, xs, devices, *args, **kwargs):
+        placed.extend(np.shape(x) for x in xs
+                      if not isinstance(x, jax.Array))
+        return put(aval, sharding, xs, devices, *args, **kwargs)
+
+    monkeypatch.setattr(pxla, "batched_device_put", spy)
+    rng = np.random.default_rng(7)
+    n = 8192
+    keys = rng.permutation(n).astype(np.int32)  # distinct: one order
+    rows = rng.integers(0, 1 << 30, (n, 24), dtype=np.int32)
+    sk, sp = TeraSorter(make_mesh(d)).sort(keys, rows)
+    np.testing.assert_array_equal(sk, np.arange(n))
+    np.testing.assert_array_equal(sp, rows[np.argsort(keys)])
+    assert all(len(s) == 1 for s in placed), placed
+    assert sum(s[0] for s in placed) == n + n * 24
