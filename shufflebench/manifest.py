@@ -3,7 +3,9 @@
 Everything that belongs to one configuration, traffic mix, job kind or
 per-layer metric lives in a file of its own under ``shufflebench/``:
 
-    configs/<config>.json    the deployment (its ``file`` in the manifest)
+    configs/<config>.json    the deployment (its ``file`` in the manifest),
+                             and, under ``context``, the shuffle context
+                             its cells run in
     traffic/<traffic>.json   the mix: loop kind and records per job
     jobs/<job>.py            inputs, API call, reference, bytes
     metrics/<metric>.py      ``read(r)``: one per-layer metric
@@ -27,6 +29,12 @@ PACKAGE = "shufflebench"
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+# conf keys a configuration's ``context`` may state, and their types
+CONTEXT_CONF = {"readPlane": str, "serializer": str,
+                "deviceExchangeEnabled": bool,
+                "deviceExchangeWindowRounds": int, "exchangeTileBytes": int}
+READ_PLANES = ("host", "windowed", "bulk")
+SERIALIZERS = ("pickle", "columnar")
 
 
 def load(root: str = ROOT) -> dict:
@@ -85,6 +93,29 @@ def metrics_of(manifest: dict, cell: str, section: str) -> List[dict]:
     """The metrics of ``section`` (``end_to_end`` or ``per_layer``)
     that ``cell`` reports."""
     return [m for m in manifest[section] if reports(m, cell, manifest)]
+
+
+def context_problems(name: str, spec) -> List[str]:
+    """What in configuration ``name``'s ``context``, a mapping of conf
+    keys without the ``spark.shuffle.tpu.`` prefix, the run would
+    misread or the program would refuse.  Only the deployment keys in
+    ``CONTEXT_CONF`` are taken: a path, a trace or metrics switch, or
+    an integrity switch would make runs meet on the host, put work in
+    the window or change a guarantee."""
+    if not isinstance(spec, dict):
+        return [f"{name}: context is not an object"]
+    out = []
+    for k, v in spec.items():
+        if k not in CONTEXT_CONF:
+            out.append(f"{name}: context key {k!r} is not one of "
+                       f"{sorted(CONTEXT_CONF)}")
+        elif type(v) is not CONTEXT_CONF[k]:
+            out.append(f"{name}: context {k} is {v!r}")
+    for k, allowed in (("readPlane", READ_PLANES),
+                       ("serializer", SERIALIZERS)):
+        if isinstance(spec.get(k), str) and spec[k] not in allowed:
+            out.append(f"{name}: {k} {spec[k]!r}, not one of {allowed}")
+    return out
 
 
 def problems(manifest: dict, root: str = ROOT) -> List[str]:
@@ -148,8 +179,14 @@ def problems(manifest: dict, root: str = ROOT) -> List[str]:
                                            m["name"] + ".py")):
             out.append(f"{m['name']}: no reader file")
     for c in manifest["configs"]:
-        if not os.path.exists(os.path.join(root, c["file"])):
+        path = os.path.join(root, c["file"])
+        if not os.path.exists(path):
             out.append(f"{c['name']}: no file {c['file']}")
+        else:
+            with open(path) as f:
+                spec = json.load(f).get("context")
+            if spec is not None:
+                out += context_problems(c["name"], spec)
         if c["name"] not in {w["config"] for w in cells.values()}:
             out.append(f"{c['name']}: used by no cell")
     four = sum(w["chips"] == 4 for w in cells.values())

@@ -6,7 +6,8 @@
 Set-up makes the cell's inputs from the seed (on the device, fetched to
 host memory, as map output in an executor), and warms the cell's job
 shapes with one job.  The window then runs jobs back to back through
-``TpuShuffleContext`` (``traffic/<mix>.json``) for ``--seconds``.
+``TpuShuffleContext`` (``traffic/<mix>.json``) for ``--seconds``, in the
+context the configuration states (:func:`_context`).
 With ``--trace 0`` the result holds the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the profiler and the result holds
 its per-layer metrics, read from the trace by ``metrics/<name>.py``.
@@ -197,6 +198,30 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     return result
 
 
+def _context(config: dict, chips: int):
+    """The shuffle context a cell's jobs run in, and its record: one
+    executor a chip on the conf keys the configuration states under
+    ``context`` (without the ``spark.shuffle.tpu.`` prefix), or, without
+    that key, one executor on the default conf."""
+    from sparkrdma_tpu.api import TpuShuffleContext
+    from sparkrdma_tpu.conf import TpuShuffleConf
+
+    conf = config.get("context")
+    if conf is None:
+        ctx = TpuShuffleContext(num_executors=1)
+        conf = {}
+    else:
+        bad = mf.context_problems(config.get("name", "config"), conf)
+        if bad:
+            raise ValueError("; ".join(bad))
+        ctx = TpuShuffleContext(
+            num_executors=chips,
+            conf=TpuShuffleConf({TpuShuffleConf.PREFIX + k: v
+                                 for k, v in conf.items()}))
+    return ctx, {"executors": len(ctx.executors), "conf": dict(conf),
+                 "read_plane": ctx.conf.read_plane}
+
+
 def _measure(job, config, traffic, chips, seed, seconds, keep, trace_dir,
              age_s):
     """Set-up, then the measured window (under the profiler when
@@ -204,20 +229,20 @@ def _measure(job, config, traffic, chips, seed, seconds, keep, trace_dir,
     run's record: set-up's parts and the compiles."""
     import jax
 
-    from sparkrdma_tpu.api import TpuShuffleContext
     from sparkrdma_tpu.parallel.mesh import make_mesh
 
     n = loop.records(traffic)
     compiles = Compiles()
     jax.monitoring.register_event_duration_secs_listener(compiles)
     mesh = make_mesh(chips)
-    ctx = TpuShuffleContext(num_executors=1)
+    ctx, context = _context(config, chips)
     try:
         t0 = time.perf_counter()
         inputs = job.make_inputs(config, n, seed)
         t1 = time.perf_counter()
         job.run(ctx, inputs, n, mesh)  # warms the one shape the window runs
-        run = {"inputs_s": t1 - t0, "warmup_s": time.perf_counter() - t1,
+        run = {"context": context,
+               "inputs_s": t1 - t0, "warmup_s": time.perf_counter() - t1,
                "setup_compiles": compiles.n,
                "setup_compile_s": compiles.seconds,
                "devices": [d.id for d in mesh.devices.flat]}
