@@ -5,34 +5,17 @@ The program opens its spans through ``sparkrdma_tpu/utils/trace.py``
 ``Tracer.span``, which writes each into the profiler's host plane as an
 event named ``shuffle.<plane>.<phase>`` with its args as stats, on the
 clock of the device operations.  :class:`shufflebench.trace.Trace`
-keeps only the benchmark's own ``window`` and ``job`` spans of that
-plane, so the events whose name starts with ``shuffle.`` are read here,
-from the same ``.xplane.pb``: ``run.py`` writes it into a
-``shufflebench-*`` directory of the temporary directory and removes
-that only once the readers have run.  :func:`of` finds it by the
-reading's ``window`` span and keeps what it read on the reading, so the
-readers of one run parse it once.  A trace of a program that opens no
-such spans gives none, and its readers give no value.
-
-A recorded trace (``tests/data/*.spans.trace.json.gz``) holds the
-program's spans beside the reduced trace, under ``"program"``
-(:func:`load`).
+keeps them, with their args, as ``Trace.program``, and a saved trace
+(``run.py --save-trace``, ``tests/data/*.spans.trace.json.gz``) carries
+them under ``"program"``.  A trace of a program that opens no such
+spans holds none, and its readers give no value.
 """
 
 from __future__ import annotations
 
-import glob
-import gzip
-import json
-import os
-import tempfile
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
-from shufflebench.trace import Trace, merge
-
-PREFIX = "shuffle."
-
-Span = Tuple[str, int, int, dict]
+from shufflebench.trace import Trace
 
 
 class Program:
@@ -40,13 +23,9 @@ class Program:
     end_ns, args), beside the reduced ``trace`` on whose jobs they are
     summed."""
 
-    def __init__(self, trace: Trace, spans: Sequence[Span] = ()):
+    def __init__(self, trace: Trace):
         self.trace = trace
-        self.spans = sorted(((str(n), int(a), int(b), dict(args))
-                             for n, a, b, args in spans),
-                            key=lambda s: s[1])
-        self._busy = [merge([(a, b) for _, a, b in evs])
-                      for evs in trace.ops.values()]
+        self.spans = trace.program
 
     def named(self, name: str, lo: int,
               hi: int) -> List[Tuple[int, int, dict]]:
@@ -66,60 +45,20 @@ class Program:
         """From ``lo`` to the first instant in ``[lo, hi)`` at which an
         operation runs (``hi`` if none does), averaged over the
         devices."""
-        if not self._busy:
+        busy = self.trace.busy.values()
+        if not busy:
             return 0.0
-        return sum(next((max(a, lo) for a, b in busy if b > lo and a < hi),
+        return sum(next((max(a, lo) for a, b in evs if b > lo and a < hi),
                         hi) - lo
-                   for busy in self._busy) / len(self._busy)
-
-
-def from_xplane(path: str) -> Tuple[Optional[Tuple[int, int]], List[Span]]:
-    """The ``window`` span and the program's spans of the ``.xplane.pb``
-    at ``path``."""
-    from jax.profiler import ProfileData
-
-    window, spans = None, []
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name == "window":
-                    window = (int(e.start_ns), int(e.end_ns))
-                elif e.name.startswith(PREFIX):
-                    spans.append((e.name, int(e.start_ns), int(e.end_ns),
-                                  dict(e.stats)))
-    return window, spans
-
-
-def _of_run(trace: Trace) -> List[Span]:
-    """The program's spans of the run whose reduced trace is ``trace``:
-    those of the newest ``.xplane.pb`` under the temporary directory's
-    ``shufflebench-*`` directories whose ``window`` span is the
-    trace's."""
-    paths = glob.glob(os.path.join(tempfile.gettempdir(), "shufflebench-*",
-                                   "**", "*.xplane.pb"), recursive=True)
-    want = trace.window()
-    for path in sorted(paths, key=os.path.getmtime, reverse=True):
-        window, spans = from_xplane(path)
-        if window == want:
-            return spans
-    return []
+                   for evs in busy) / len(busy)
 
 
 def of(r) -> Program:
-    """The program's spans of reading ``r`` (``run.Reading``), read
-    once and kept on it."""
-    p = getattr(r, "program", None)
-    if p is None:
-        p = r.program = Program(r.trace, _of_run(r.trace))
-    return p
+    """The program's spans of reading ``r`` (``run.Reading``)."""
+    return Program(r.trace)
 
 
 def load(path: str) -> Tuple[Trace, Program]:
     """A recorded trace and the program's spans it holds."""
     trace = Trace.from_json(path)
-    with gzip.open(path, "rt") as f:
-        spans = json.load(f).get("program", ())
-    return trace, Program(trace, spans)
-
+    return trace, Program(trace)

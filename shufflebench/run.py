@@ -303,7 +303,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--save-trace", metavar="PATH",
-                    help="also write the reduced trace (gzip JSON) here")
+                    help="also write the reduced trace, the program's "
+                    "spans with it (gzip JSON), here")
     args = ap.parse_args(argv)
 
     os.environ["JAX_COMPILATION_CACHE_DIR"] = CACHE_DIR

@@ -20,6 +20,8 @@ if ROOT not in sys.path:
 
 import pytest  # noqa: E402
 
+from shufflebench import manifest as mf  # noqa: E402
+
 # job sizes a test run holds, by job kind; WordCount's is off the
 # compile ladder, as the cell's 64M is
 TINY = {"rows": 1 << 17, "words": 100_000}
@@ -47,3 +49,67 @@ def tiny_root(tmp_path):
     with open(tmp_path / "BENCHMARK.json", "w") as f:
         json.dump(manifest, f)
     return str(tmp_path)
+
+
+# TeraSort's rows through the shuffle manager: the 96-byte payload as
+# one void column beside the key, sorted by the record plane
+RECORD_JOB = '''
+import os
+
+import numpy as np
+
+from shufflebench import manifest as mf
+
+_ts = mf.plugin("jobs", "terasort",
+                os.path.dirname(os.path.dirname(os.path.dirname(
+                    os.path.abspath(__file__)))))
+LIMITS = _ts.LIMITS
+make_inputs, reference, compare = _ts.make_inputs, _ts.reference, _ts.compare
+least_bytes = _ts.least_bytes
+
+
+def run(ctx, inputs, n, mesh):
+    words = inputs["payload"].shape[1]
+    rows = inputs["payload"][:n].view(f"V{4 * words}").reshape(n)
+    out = ctx.parallelize_columns(inputs["keys"][:n], rows).sort_by_key()
+    recs = out.collect()
+    keys = np.fromiter((k for k, _ in recs), np.int32, len(recs))
+    payload = np.frombuffer(b"".join([v for _, v in recs]), np.int32)
+    return keys, payload.reshape(len(recs), words)
+'''
+
+
+def dropin_record_cell(root: str, chips: int, rows: int = 8192,
+                       **conf) -> str:
+    """Drop into the checkout ``root`` a configuration that states a
+    bulk, columnar context (with the conf keys ``conf`` beside), a
+    record-plane job, a mix of ``rows`` rows a job and a cell, as new
+    files and entries only, as a later PR would.  Every name is the
+    test's own (``dropin``).  Returns the cell's name."""
+    sb = os.path.join(root, "shufflebench")
+    with open(os.path.join(sb, "configs", "hibench_terasort.json")) as f:
+        cfg = json.load(f)
+    cfg.update(name="dropin_records", job="dropin_record_sort",
+               context={"readPlane": "bulk", "serializer": "columnar",
+                        **conf})
+    with open(os.path.join(sb, "configs", "dropin_records.json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(sb, "jobs", "dropin_record_sort.py"), "w") as f:
+        f.write(RECORD_JOB)
+    with open(os.path.join(sb, "traffic", "dropin_records.json"), "w") as f:
+        json.dump({"loop": "closed", "records_per_job": rows}, f)
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        m = json.load(f)
+    m["configs"].append({"name": "dropin_records",
+                         "source": cfg["source"],
+                         "file": "shufflebench/configs/dropin_records.json",
+                         "reduced": ["key_bytes"], "why": "the record plane"})
+    name = f"dropin.bulk{chips}"
+    m["workloads"].append({"name": name, "config": "dropin_records",
+                           "traffic": "dropin_records", "chips": chips,
+                           "why": "sortByKey through the shuffle manager"})
+    with open(path, "w") as f:
+        json.dump(m, f)
+    assert mf.problems(mf.load(root), root) == []
+    return name

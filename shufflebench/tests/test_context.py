@@ -3,41 +3,11 @@ that, a cell runs in one executor on the default conf."""
 
 import copy
 import io
-import json
-import os
 
 import pytest
 
-from conftest import ROOT
+from conftest import ROOT, dropin_record_cell
 from shufflebench import manifest as mf, run
-
-# TeraSort's rows through the shuffle manager: the 96-byte payload as
-# one void column beside the key, sorted by the record plane
-RECORD_JOB = '''
-import os
-
-import numpy as np
-
-from shufflebench import manifest as mf
-
-_ts = mf.plugin("jobs", "terasort",
-                os.path.dirname(os.path.dirname(os.path.dirname(
-                    os.path.abspath(__file__)))))
-LIMITS = _ts.LIMITS
-make_inputs, reference, compare = _ts.make_inputs, _ts.reference, _ts.compare
-least_bytes = _ts.least_bytes
-
-
-def run(ctx, inputs, n, mesh):
-    words = inputs["payload"].shape[1]
-    rows = inputs["payload"][:n].view(f"V{4 * words}").reshape(n)
-    out = ctx.parallelize_columns(inputs["keys"][:n], rows).sort_by_key()
-    recs = out.collect()
-    keys = np.fromiter((k for k, _ in recs), np.int32, len(recs))
-    payload = np.frombuffer(b"".join([v for _, v in recs]), np.int32)
-    return keys, payload.reshape(len(recs), words)
-'''
-
 
 @pytest.fixture
 def built(monkeypatch):
@@ -73,42 +43,10 @@ def test_a_config_without_context_runs_one_executor_on_the_default_conf(
                                    "read_plane": "host"}
 
 
-def _record_cell(root: str, chips: int) -> str:
-    """Drop in a configuration that states a bulk, columnar context, a
-    record-plane job, a small mix and a cell, as a later PR would."""
-    sb = os.path.join(root, "shufflebench")
-    with open(os.path.join(sb, "configs", "hibench_terasort.json")) as f:
-        cfg = json.load(f)
-    cfg.update(name="terasort_records", job="record_sort",
-               context={"readPlane": "bulk", "serializer": "columnar"})
-    with open(os.path.join(sb, "configs", "terasort_records.json"),
-              "w") as f:
-        json.dump(cfg, f)
-    with open(os.path.join(sb, "jobs", "record_sort.py"), "w") as f:
-        f.write(RECORD_JOB)
-    with open(os.path.join(sb, "traffic", "records_8k.json"), "w") as f:
-        json.dump({"loop": "closed", "records_per_job": 8192}, f)
-    path = os.path.join(root, "BENCHMARK.json")
-    with open(path) as f:
-        m = json.load(f)
-    m["configs"].append({"name": "terasort_records",
-                         "source": cfg["source"],
-                         "file": "shufflebench/configs/terasort_records.json",
-                         "reduced": ["key_bytes"], "why": "the record plane"})
-    name = f"records.bulk{chips}"
-    m["workloads"].append({"name": name, "config": "terasort_records",
-                           "traffic": "records_8k", "chips": chips,
-                           "why": "sortByKey through the shuffle manager"})
-    with open(path, "w") as f:
-        json.dump(m, f)
-    assert mf.problems(mf.load(root), root) == []
-    return name
-
-
 @pytest.mark.parametrize("chips", [1, 4])
 def test_a_config_with_context_gets_the_plane_it_names(tiny_root, built,
                                                        chips):
-    name = _record_cell(tiny_root, chips)
+    name = dropin_record_cell(tiny_root, chips)
     r = run.run_cell(name, 12, 0.2, False, root=tiny_root,
                      require_tpu=False, log=io.StringIO())
     assert r["correct"], r["checks"]

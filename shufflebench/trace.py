@@ -1,18 +1,23 @@
 """Reduction of a profiler trace to what the per-layer metrics read.
 
-A ``.xplane.pb`` (``jax.profiler``) is reduced to two things, both on
+A ``.xplane.pb`` (``jax.profiler``) is reduced to three things, all on
 the trace's one clock in nanoseconds:
 
 - per device, the operations that ran on it: the events of the device
   plane's "XLA Ops" line (name, start, end);
 - the benchmark's own host spans (``jax.profiler.TraceAnnotation``):
   ``window`` around the measured loop, ``job`` around each call into
-  the user API.
+  the user API;
+- the program's own host spans, opened through
+  ``sparkrdma_tpu/utils/trace.py`` ``Tracer.span`` on any host thread:
+  the events whose name starts with ``shuffle.``, with their args (the
+  event's stats).
 
 Everything the readers in ``metrics/`` need is computed here from that
-reduction, so each PR reads the same trace the same way.  A reduced
-trace saves to JSON (:meth:`Trace.to_json`), which is how the recorded
-chip trace in ``tests/`` is kept.
+reduction (the program's spans through ``program_spans.py``), so each
+PR reads the same trace the same way.  A reduced trace saves to JSON
+(:meth:`Trace.to_json`), which is how the recorded chip traces in
+``tests/`` are kept.
 """
 
 from __future__ import annotations
@@ -24,10 +29,12 @@ import re
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 SPANS = ("window", "job")
+PROGRAM = "shuffle."
 _DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU|CPU):(\d+)$")
 OPS_LINE = "XLA Ops"
 
 Interval = Tuple[int, int]
+ProgramSpan = Tuple[str, int, int, dict]
 
 
 def short_name(name: str) -> str:
@@ -78,17 +85,24 @@ class Trace:
 
     ``ops`` maps a device id to its operations as (name, start_ns,
     end_ns); ``spans`` lists the benchmark's host spans as (name,
-    start_ns, end_ns)."""
+    start_ns, end_ns); ``program`` the program's host spans as (name,
+    start_ns, end_ns, args).  Only ``spans`` delimit the window and its
+    jobs and label the idle gaps.  ``busy`` maps a device id to the
+    disjoint union of its operations' intervals."""
 
     def __init__(self, ops: Dict[int, List[Tuple[str, int, int]]],
-                 spans: List[Tuple[str, int, int]]):
+                 spans: List[Tuple[str, int, int]],
+                 program: Sequence[ProgramSpan] = ()):
         self.ops = {int(d): sorted((short_name(str(n)), int(a), int(b))
                                    for n, a, b in evs)
                     for d, evs in ops.items()}
         self.spans = sorted(((str(n), int(a), int(b)) for n, a, b in spans),
                             key=lambda s: s[1])
-        self._busy = {d: merge([(a, b) for _, a, b in evs])
-                      for d, evs in self.ops.items()}
+        self.program = sorted(((str(n), int(a), int(b), dict(args))
+                               for n, a, b, args in program),
+                              key=lambda s: s[1])
+        self.busy = {d: merge([(a, b) for _, a, b in evs])
+                     for d, evs in self.ops.items()}
 
     # -- loading and saving ------------------------------------------------
     @classmethod
@@ -100,6 +114,7 @@ class Trace:
 
         ops: Dict[int, List[Tuple[str, int, int]]] = {}
         spans: List[Tuple[str, int, int]] = []
+        program: List[ProgramSpan] = []
         for plane in ProfileData.from_file(path).planes:
             m = _DEVICE_PLANE.match(plane.name)
             if m and m.group(1) != "CPU":
@@ -114,23 +129,28 @@ class Trace:
                         )
             elif plane.name.startswith("/host:"):
                 for line in plane.lines:
-                    spans.extend(
-                        (e.name, int(e.start_ns), int(e.end_ns))
-                        for e in line.events if e.name in SPANS
-                    )
-        return cls(ops, spans)
+                    for e in line.events:
+                        if e.name in SPANS:
+                            spans.append((e.name, int(e.start_ns),
+                                          int(e.end_ns)))
+                        elif e.name.startswith(PROGRAM):
+                            program.append((e.name, int(e.start_ns),
+                                            int(e.end_ns), dict(e.stats)))
+        return cls(ops, spans, program)
 
     def to_json(self, path: str) -> None:
         with gzip.open(path, "wt") as f:
             json.dump({"ops": {str(d): evs for d, evs in self.ops.items()},
-                       "spans": self.spans}, f)
+                       "spans": self.spans, "program": self.program}, f)
 
     @classmethod
     def from_json(cls, path: str) -> "Trace":
+        """A saved trace; one saved before the program's spans were kept
+        has none."""
         with gzip.open(path, "rt") as f:
             data = json.load(f)
         return cls({int(d): evs for d, evs in data["ops"].items()},
-                   data["spans"])
+                   data["spans"], data.get("program", ()))
 
     # -- spans ---------------------------------------------------------------
     def spans_named(self, name: str) -> List[Interval]:
@@ -156,10 +176,10 @@ class Trace:
     def busy_ns(self, lo: int, hi: int) -> float:
         """Time in ``[lo, hi)`` in which some operation ran, averaged
         over the devices."""
-        if not self._busy:
+        if not self.busy:
             return 0.0
-        return sum(covered(b, lo, hi) for b in self._busy.values()) / len(
-            self._busy)
+        return sum(covered(b, lo, hi) for b in self.busy.values()) / len(
+            self.busy)
 
     def idle_share(self) -> float:
         lo, hi = self.window()
