@@ -29,6 +29,10 @@ of the reference's maxAggBlock fetch cap (SURVEY.md §7 hard parts).
 from __future__ import annotations
 
 import functools
+import math
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Tuple
 
 import jax
@@ -46,6 +50,57 @@ def _read_only(*arrays: np.ndarray) -> Tuple[np.ndarray, ...]:
     for a in arrays:
         a.flags.writeable = False
     return arrays
+
+
+STITCH_CHUNK_BYTES = 32 << 20  # most bytes of a column one stitch copy moves
+STITCH_WORKERS = 16  # most host threads the stitch copies on
+
+
+@functools.cache
+def _stitch_pool() -> ThreadPoolExecutor:
+    """The host threads the stitch copies on: one pool a process, made
+    on first use (``device_sort`` makes a TeraSorter a call), a thread a
+    usable CPU up to STITCH_WORKERS.  They make no JAX call."""
+    return ThreadPoolExecutor(
+        min(STITCH_WORKERS, len(os.sched_getaffinity(0))),
+        thread_name_prefix="terasort-stitch")
+
+
+# a forked child inherits the pool but none of its threads
+os.register_at_fork(after_in_child=_stitch_pool.cache_clear)
+
+
+def _join_runs(runs, nv):
+    """Each column's per-device runs trimmed to their valid counts
+    ``nv`` and joined into one fresh C-ordered array, the bytes
+    ``np.concatenate`` gives.  Each device's rows are copied in row
+    chunks of at most STITCH_CHUNK_BYTES into their slice of the result;
+    a result of more than one chunk is copied on the stitch pool, so the
+    first touch of its fresh pages is spread over host threads.  Returns
+    (read-only columns, copies made, threads that made them)."""
+    nv = np.asarray(nv).tolist()
+    offs = np.cumsum([0] + nv).tolist()  # where each device's rows go
+    outs, copies = [], []
+    for r in runs:
+        out = np.empty((offs[-1],) + r[0].shape[1:], r[0].dtype)
+        step = max(1, STITCH_CHUNK_BYTES // max(
+            1, out.itemsize * math.prod(out.shape[1:])))
+        for run, at, n in zip(r, offs, nv):
+            for lo in range(0, n, step):
+                hi = min(lo + step, n)
+                copies.append((out[at + lo:at + hi], run[lo:hi]))
+        outs.append(out)
+
+    def copy(dst_src):
+        dst, src = dst_src
+        dst[...] = src  # numpy lets go of the GIL for the copy
+        return threading.get_ident()
+
+    if sum(o.nbytes for o in outs) <= STITCH_CHUNK_BYTES:
+        threads = set(map(copy, copies))
+    else:
+        threads = set(_stitch_pool().map(copy, copies))
+    return _read_only(*outs), len(copies), len(threads)
 
 
 def _sample_positions(n_local: int, sample_size: int) -> np.ndarray:
@@ -514,18 +569,16 @@ class TeraSorter(ExchangeModel):
     def _stitch(self, runs, nv):
         """The sorted result from the per-device runs, each trimmed to
         its valid count: on one device a view of the fetched run, on
-        several one copy of the valid rows.  Read-only either way."""
-        D = self.n_devices
-        with get_tracer().span("shuffle.device.stitch") as sp:
-            if D == 1:
+        several one copy of the valid rows, made on host threads
+        (:func:`_join_runs`).  Read-only either way."""
+        with faulting_span("shuffle.device.stitch") as sp:
+            if self.n_devices == 1:
                 out = tuple(r[0][: nv[0]] for r in runs)
-                copied = 0
+                copied = chunks = workers = 0
             else:
-                out = _read_only(*(
-                    np.concatenate([r[d][: nv[d]] for d in range(D)])
-                    for r in runs))
+                out, chunks, workers = _join_runs(runs, nv)
                 copied = sum(o.nbytes for o in out)
-            sp.set(bytes=copied)
+            sp.set(bytes=copied, chunks=chunks, workers=workers)
         return out
 
     def _sort_wide(self, keys: np.ndarray, payload: np.ndarray):

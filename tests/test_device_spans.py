@@ -127,9 +127,13 @@ def test_device_sort_wide_rows_emit_the_driver_spans(tmp_path, devices, d):
     assert fetch[3]["result_bytes"] == sk.nbytes + sp.nbytes
     assert fetch[3]["shards"] == d
     # one device: the result is a view of the fetched run; several: one
-    # copy of the valid rows
+    # copy of the valid rows, a chunk a device and column at this size,
+    # on the calling thread
     (stitch,) = named(events, DEVICE + "stitch")
     assert stitch[3]["bytes"] == (0 if d == 1 else sk.nbytes + sp.nbytes)
+    assert stitch[3]["chunks"] == (0 if d == 1 else 2 * d)
+    assert stitch[3]["workers"] == (0 if d == 1 else 1)
+    assert "minflt" in stitch[3]
 
 
 def test_device_sort_padded_keys_emit_pad_and_place(tmp_path, devices):
@@ -154,6 +158,8 @@ def test_device_sort_padded_keys_emit_pad_and_place(tmp_path, devices):
     assert fetch[3]["result_bytes"] == sk.nbytes + sv.nbytes
     assert fetch[3]["shards"] == 4
     assert stitch[3]["bytes"] == sk.nbytes + sv.nbytes
+    assert (stitch[3]["chunks"], stitch[3]["workers"]) == (8, 1)
+    assert "minflt" in stitch[3]
 
 
 @pytest.mark.parametrize("d", [1, 4])

@@ -787,6 +787,50 @@ def test_terasort_exact_from_per_device_runs(devices, monkeypatch, d, wide):
     assert not any(e.flags.writeable for e in empty)
 
 
+@pytest.mark.parametrize("chunk_bytes", [1 << 10, 32 << 20])
+@pytest.mark.parametrize("d", [1, 4])
+@pytest.mark.parametrize("width", [None, 24])
+def test_stitch_joins_the_valid_rows_exactly(devices, monkeypatch,
+                                             chunk_bytes, d, width):
+    """The stitch's result holds the bytes ``np.concatenate`` gives of
+    the devices' valid prefixes, C-ordered and read-only: copied in
+    chunks on the stitch pool when the result is more than one chunk
+    (valid counts off the chunk, a device with none), on the calling
+    thread otherwise; on one device a view of the run, no copy."""
+    from sparkrdma_tpu.models import terasort
+
+    monkeypatch.setattr(terasort, "STITCH_CHUNK_BYTES", chunk_bytes)
+    rng = np.random.default_rng(d * 7 + (width or 1))
+    cap = 3001
+    nv = np.array([2999, 0, 1031, cap][:d], np.int32)
+    keys = [rng.integers(-1 << 31, 1 << 31, cap, dtype=np.int64)
+            .astype(np.int32) for _ in range(d)]
+    vals = [rng.integers(-1 << 31, 1 << 31, (cap, width), dtype=np.int64)
+            .astype(np.int32) if width else rng.standard_normal(cap)
+            for _ in range(d)]
+    runs = [keys, vals]
+    for run in keys + vals:
+        run.flags.writeable = False  # as the fetch hands them over
+    pool_calls = terasort._stitch_pool.cache_info()
+    out = TeraSorter(make_mesh(d))._stitch(runs, nv)
+    pool_calls = sum(terasort._stitch_pool.cache_info()[:2]) - sum(
+        pool_calls[:2])
+    for o, r in zip(out, runs):
+        ref = np.concatenate([r[i][:nv[i]] for i in range(d)])
+        assert (o.dtype, o.shape) == (ref.dtype, ref.shape)
+        assert o.tobytes() == ref.tobytes()
+        assert o.flags.c_contiguous and not o.flags.writeable
+        assert np.shares_memory(o, r[0]) == (d == 1)
+    pooled = d > 1 and chunk_bytes < sum(o.nbytes for o in out)
+    assert pool_calls == pooled
+    if d > 1:
+        _, chunks, workers = terasort._join_runs(runs, nv)
+        rows = [max(1, chunk_bytes // r[0][:1].nbytes) for r in runs]
+        assert chunks == sum(-(-int(n) // c) for c in rows for n in nv)
+        assert 1 <= workers <= (
+            terasort._stitch_pool()._max_workers if pooled else 1)
+
+
 @pytest.mark.parametrize("model", ["count", "aggregate", "top_k"])
 def test_keyed_models_exact_from_per_device_runs(devices, monkeypatch,
                                                  model):
