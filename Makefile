@@ -1,6 +1,6 @@
 # Build/test entry points (the pom.xml analog).
 
-.PHONY: all native lint concheck flowcheck wirecheck statecheck test bench bench-smoke bench-cluster bench-device chaos chaos-shake dryrun clean
+.PHONY: all native lint concheck flowcheck wirecheck statecheck test chaos chaos-shake dryrun clean
 
 all: native
 
@@ -47,48 +47,6 @@ statecheck:
 
 test: native lint
 	python -m pytest tests/ -x -q
-
-bench: native
-	python bench.py
-
-# tier-2 sanity gate: the reduce-loopback bench (record plane, striped
-# fetch, decode pipeline) plus the out-of-core tier sweep, in tiny
-# configs — same code paths, seconds not minutes, JSON written to /tmp
-# so committed results stay intact
-bench-smoke:
-	BENCH_SMOKE=1 SPARKRDMA_TPU_BENCH_SPOOFED=1 JAX_PLATFORMS=cpu \
-	python benchmarks/bench_reduce_loopback.py
-	BENCH_SMOKE=1 SPARKRDMA_TPU_BENCH_SPOOFED=1 JAX_PLATFORMS=cpu \
-	python benchmarks/bench_terasort.py --out-of-core
-	BENCH_SMOKE=1 SPARKRDMA_TPU_BENCH_SPOOFED=1 JAX_PLATFORMS=cpu \
-	python benchmarks/bench_qos.py
-	BENCH_SMOKE=1 SPARKRDMA_TPU_BENCH_SPOOFED=1 JAX_PLATFORMS=cpu \
-	python benchmarks/bench_skew.py
-	BENCH_SMOKE=1 SPARKRDMA_TPU_BENCH_SPOOFED=1 JAX_PLATFORMS=cpu \
-	python benchmarks/bench_cluster.py
-	BENCH_SMOKE=1 SPARKRDMA_TPU_BENCH_SPOOFED=1 JAX_PLATFORMS=cpu \
-	python benchmarks/bench_push.py
-	BENCH_SMOKE=1 SPARKRDMA_TPU_BENCH_SPOOFED=1 JAX_PLATFORMS=cpu \
-	XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	python benchmarks/bench_device_exchange.py
-	python tools/bench_gate.py
-	$(MAKE) chaos
-	$(MAKE) chaos-shake
-
-# the multi-process cluster tier alone (real executor processes over
-# TCP + the native hot-path kernel microbench); full config writes
-# BENCH_cluster.json at the repo root
-bench-cluster: native
-	JAX_PLATFORMS=cpu python benchmarks/bench_cluster.py
-
-# the device-native exchange tier alone (padded collective plane,
-# bucketized headline, end-to-end loopback clusters) on a spoofed
-# ≥2-device CPU mesh; full config writes BENCH_device_exchange.json
-# at the repo root
-bench-device:
-	BENCH_SMOKE=1 SPARKRDMA_TPU_BENCH_SPOOFED=1 JAX_PLATFORMS=cpu \
-	XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	python benchmarks/bench_device_exchange.py
 
 # the seeded chaos soak alone (faults/, conf faultInject): the full
 # engine matrix — loopback / tcp-threaded / tcp-async × decode

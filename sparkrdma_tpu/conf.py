@@ -364,12 +364,12 @@ class TpuShuffleConf:
         (the RdmaMappedFile ODP-prefetch sweep, RdmaMappedFile.java:
         158-168, re-aimed at the disk tier).  ``off`` keeps the tier a
         plain demand cache — every cold block pays its disk read on
-        the serve path (the A/B the out-of-core bench measures).
+        the serve path.
         Default: enabled on multi-core hosts; on a single core the
         warm work only timeslices against the serves it is meant to
-        hide (measured net-negative there — the ``decodeThreads`` /
-        ``bulkPipelineWindows`` single-core-fallback precedent).  An
-        explicit setting always wins."""
+        hide (the ``decodeThreads`` / ``bulkPipelineWindows``
+        single-core-fallback precedent).  An explicit setting always
+        wins."""
         return self._bool("tierPrefetch", self.core_census > 1)
 
     @property
@@ -445,11 +445,9 @@ class TpuShuffleConf:
         (the registered-ring-size analog of the RDMA QP); the kernel
         doubles the requested value and caps it at
         ``net.core.{w,r}mem_max``.  ``0`` — the default — keeps kernel
-        autotuning: pinning at 4 MiB was A/B'd ~15% SLOWER than
-        autotune on the loopback bench (setting SO_RCVBUF freezes the
-        buffer where autotune keeps growing it with the BDP), so the
-        knob exists for real fabrics with known ring budgets, not as a
-        default."""
+        autotuning: setting SO_RCVBUF freezes the buffer where
+        autotune keeps growing it with the BDP, so the knob exists for
+        real fabrics with known ring budgets, not as a default."""
         return self._bytes_in_range(
             "transportSocketBufferBytes", 0, 0, 1 << 30
         )

@@ -873,7 +873,7 @@ class ShuffleReader:
         # the wire — the disk reads overlap the transfer instead of
         # serializing behind it
         self._send_hint(fetch.host)
-        # the push/pull RPC ledger the bench reads: one increment per
+        # the push/pull RPC ledger tests read: one increment per
         # read RPC actually put on the wire (retries re-count — they
         # ARE another RPC)
         mode = "push" if fetch.merged is not None else "pull"

@@ -1,6 +1,6 @@
 """Dry-run peer fleet: N wire-protocol peers on ONE selector thread.
 
-Scale tests and the fabric-scale bench need hundreds of fetchable peers
+Scale tests need hundreds of fetchable peers
 without paying hundreds of real :class:`~sparkrdma_tpu.transport.node.Node`
 instances (each with its own dispatcher loop and pools — the very cost
 the bounded fabric exists to avoid paying per peer).  A
@@ -360,7 +360,7 @@ _PORT_SPACING = 40  # > portMaxRetries so per-child bind hunts don't collide
 
 def _process_census() -> dict:
     """CPU/fd/thread census of THIS process (parent and children both
-    report through it, so bench_cluster can sum a fleet)."""
+    report through it, so a caller can sum a fleet)."""
     import os
 
     try:

@@ -1,5 +1,5 @@
 """Where JAX keeps its persistent compilation cache for this repo's
-entry points (``chip_smoke.py``, ``bench.py``, ``benchmarks/``).
+entry point ``chip_smoke.py``.
 
 ``JAX_COMPILATION_CACHE_DIR``, when set, is the place: JAX reads it
 itself and nothing else is set.  Otherwise the cache lives at

@@ -60,8 +60,8 @@ def test_py08_flags_perf_counter_in_library_code(tmp_path):
 
 def test_py08_ignores_non_library_code(tmp_path):
     lint = _load_lint()
-    (tmp_path / "benchmarks").mkdir()
-    bench = tmp_path / "benchmarks" / "b.py"
+    (tmp_path / "shufflebench").mkdir()
+    bench = tmp_path / "shufflebench" / "b.py"
     bench.write_text("import time\nT0 = time.perf_counter()\n")
     findings = []
     lint.lint_python(bench, findings, root=tmp_path)

@@ -99,7 +99,7 @@ def test_full_sort_skewed_keys():
 @pytest.mark.parametrize("pattern", ["sorted", "reverse", "constant"])
 def test_full_sort_adversarial_patterns(pattern):
     """Pre-sorted, reverse-sorted, and all-equal inputs (the splitter
-    sampling's worst cases) must stay exact — bench.py may adopt this
+    sampling's worst cases) must stay exact — a caller may adopt this
     engine unattended on hardware."""
     block_rows = 8
     B = block_rows * LANES

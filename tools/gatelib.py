@@ -101,12 +101,12 @@ class Finding(NamedTuple):
 
 # -- file walking -------------------------------------------------------------
 
-PY_DIRS = ["sparkrdma_tpu", "tests", "benchmarks", "tools"]
+PY_DIRS = ["sparkrdma_tpu", "tests", "tools"]
 
 
 def py_files(root: pathlib.Path = ROOT):
     """The repo-wide python walk (lint's scope): the library, tests,
-    benches, tools, plus repo-root scripts."""
+    tools, plus repo-root scripts."""
     for d in PY_DIRS:
         yield from sorted((root / d).rglob("*.py"))
     yield from sorted(root.glob("*.py"))

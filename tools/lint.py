@@ -12,7 +12,7 @@ silences everything, but a scoped escape can never blanket-silence an
 unrelated hot-path rule.  ``F401`` is accepted as an alias for PY05
 (flake8 compatibility).
 
-Python (sparkrdma_tpu/, tests/, benchmarks/, tools/, repo-root *.py):
+Python (sparkrdma_tpu/, tests/, tools/, repo-root *.py):
   PY01  file does not parse (SyntaxError)
   PY02  line longer than 88 characters
   PY03  tab character in indentation
@@ -25,7 +25,7 @@ Python (sparkrdma_tpu/, tests/, benchmarks/, tools/, repo-root *.py):
         own line)
   PY06  bare ``except:`` (use ``except BaseException:`` when you truly
         mean everything)
-  PY07  ``print(`` in library code (sparkrdma_tpu/ only; benches, tests
+  PY07  ``print(`` in library code (sparkrdma_tpu/ only; scripts, tests
         and tools print by design)
   PY08  ``time.perf_counter()`` in library code outside
         sparkrdma_tpu/metrics/ and sparkrdma_tpu/utils/trace.py —
